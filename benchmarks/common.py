@@ -246,10 +246,10 @@ def get_executor(name: str, method: str, use_pallas: bool = False,
         # collective program for identical params over and over
         ex = _DISTRIBUTED_EXECUTORS.get(name)
         if ex is None:
-            from repro import compat
             from repro.core.distributed import (DistributedScannExecutor,
                                                 shard_index)
-            mesh = compat.make_mesh((jax.device_count(),), ("data",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((jax.device_count(),), ("data",))
             sharded = shard_index(get_scann(name), store, mesh, "data")
             ex = _DISTRIBUTED_EXECUTORS[name] = \
                 DistributedScannExecutor(sharded)

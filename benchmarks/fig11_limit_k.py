@@ -29,4 +29,6 @@ def run(ds="sift10m", sel=0.05) -> list[dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     emit(run(), "fig11")

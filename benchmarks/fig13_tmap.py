@@ -38,4 +38,6 @@ def run(ds="openai5m") -> list[dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     emit(run(), "fig13")

@@ -45,4 +45,6 @@ def run(datasets=("sift10m", "openai5m"), sels=SELECTIVITIES) -> list[dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     emit(run(), "fig9")

@@ -23,4 +23,6 @@ def run() -> list[dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     emit(run(), "table2")

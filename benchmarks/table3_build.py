@@ -37,4 +37,6 @@ def run(datasets=("sift10m", "openai5m")) -> list[dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     emit(run(), "table3")

@@ -53,4 +53,6 @@ def run(ds="openai5m", sels=SELECTIVITIES, storage=False) -> list[dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     emit(run(storage="--storage" in sys.argv[1:]), "table6")

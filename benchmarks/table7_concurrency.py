@@ -90,4 +90,6 @@ def run(ds="openai5m", sel=0.1) -> list[dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     emit(run(), "table7")

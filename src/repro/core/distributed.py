@@ -25,8 +25,6 @@ import dataclasses
 from functools import partial
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -35,7 +33,7 @@ from repro.core.scann import (ScannIndex, _quant_pages_per_leaf,
                               build_scann)
 from repro.core.types import SearchParams, SearchStats, VectorStore, \
     distance, heap_pages_per_vector, probe_bitmap, topk_smallest
-from repro.kernels import ref as kref
+from repro.kernels import ops as kops
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,11 +46,19 @@ class ShardedFVS:
     axis: str                  # mesh axis (or flattened axes) leaves shard on
 
 
+def place_shards(tree, mesh: Mesh, axis: str):
+    """Put every array leaf of `tree` on the mesh, split along its
+    leading axis over mesh axis `axis` (each device holds 1/size)."""
+    return jax.device_put(tree, NamedSharding(mesh, P(axis)))
+
+
 def shard_index(index: ScannIndex, store: VectorStore, mesh: Mesh,
                 axis: str) -> ShardedFVS:
     """Pad leaf count to a multiple of the axis size; device d owns leaves
-    [d*Lp, (d+1)*Lp). Heap stays globally addressed (rows gathered only on
-    the owner — local leaves only reference local-shard rows by build)."""
+    [d*Lp, (d+1)*Lp), placed there.  The quantizer, PCA and heap are
+    replicated on every device (the heap stays globally addressed: rows
+    are gathered only on the owner — local leaves only reference
+    local-shard rows by build)."""
     nd = mesh.shape[axis]
     L = index.num_leaves
     pad = (-L) % nd
@@ -66,6 +72,17 @@ def shard_index(index: ScannIndex, store: VectorStore, mesh: Mesh,
                                    ((0, pad), (0, 0)),
                                    constant_values=jnp.inf),
         )
+    leaves = place_shards(dict(leaf_tiles=index.leaf_tiles,
+                               leaf_rowids=index.leaf_rowids,
+                               leaf_centroids=index.leaf_centroids),
+                          mesh, axis)
+    rep = NamedSharding(mesh, P())
+    index = dataclasses.replace(
+        index, **leaves, **jax.device_put(
+            dict(scale=index.scale, mean=index.mean, pca=index.pca), rep))
+    store = dataclasses.replace(
+        store, vectors=jax.device_put(store.vectors, rep),
+        norms_sq=jax.device_put(store.norms_sq, rep))
     return ShardedFVS(index=index, store=store, mesh=mesh, axis=axis)
 
 
@@ -114,21 +131,16 @@ def distributed_search_raw(sharded: ShardedFVS, params: SearchParams,
 
         def one(q, bm):
             proj, mu_p = pca[:-1], pca[-1]
-            qp = q @ proj - mu_p
+            qp = jnp.matmul(q, proj,
+                            precision=jax.lax.Precision.HIGHEST) - mu_p
             cd = distance(metric, qp[None], cents,
                           jnp.sum(cents * cents, -1))
             cd = jnp.where(jnp.isfinite(cents[:, 0]), cd, jnp.inf)
             nsel = min(max(1, -(-nl // mesh.shape[axis])), cents.shape[0])
             _, leaves = topk_smallest(cd, nsel)
-            if use_pallas:
-                from repro.kernels.leaf_scan import leaf_scan_pallas
-                scores = leaf_scan_pallas(
-                    qp, tiles[leaves], rowids[leaves], scale, mean, bm,
-                    metric, interpret=jax.default_backend() != "tpu")
-            else:
-                scores = kref.leaf_scan_ref(qp, tiles[leaves],
-                                            rowids[leaves], scale, mean,
-                                            bm, metric)
+            scores = kops.leaf_scan(qp, tiles[leaves], rowids[leaves],
+                                    scale, mean, bm, metric,
+                                    use_pallas=use_pallas)
             r = min(k * params.reorder_factor, nsel * tiles.shape[1])
             fs, fp = topk_smallest(scores.reshape(-1), r)
             rows = rowids[leaves].reshape(-1)[fp]
@@ -179,7 +191,7 @@ def distributed_search_raw(sharded: ShardedFVS, params: SearchParams,
     pspec = P(axis)
     rep = P()
     vspec = P(axis) if heap_layout == "leaf_ordered" else rep
-    return compat.shard_map(
+    return jax.shard_map(
         local_search, mesh=mesh,
         in_specs=(pspec, pspec, pspec, rep, rep, rep, vspec, vspec,
                   rep, rep),
@@ -191,19 +203,18 @@ def distributed_search_fn(sharded: ShardedFVS, params: SearchParams,
                           use_pallas: bool = False,
                           heap_layout: str = "replicated",
                           with_stats: bool = False):
-    """Jittable distributed filtered-search step bound to a concrete store:
-    (queries (Q, d), bitmaps (Q, W)) -> (dists (Q, k), ids[, stats])."""
-    fn = distributed_search_raw(sharded, params, use_pallas=use_pallas,
-                                heap_layout=heap_layout,
-                                with_stats=with_stats)
+    """Distributed filtered-search step bound to a concrete store:
+    (queries (Q, d), bitmaps (Q, W)) -> (dists (Q, k), ids[, stats]).
+    The placed index tiers are arguments of the jitted step, not
+    constants folded into it."""
+    fn = jax.jit(distributed_search_raw(sharded, params,
+                                        use_pallas=use_pallas,
+                                        heap_layout=heap_layout,
+                                        with_stats=with_stats))
     idx, store = sharded.index, sharded.store
-
-    def search(queries, bitmaps):
-        return fn(idx.leaf_tiles, idx.leaf_rowids, idx.leaf_centroids,
-                  idx.scale, idx.mean, idx.pca, store.vectors,
-                  store.norms_sq, queries, bitmaps)
-
-    return jax.jit(search)
+    return partial(fn, idx.leaf_tiles, idx.leaf_rowids, idx.leaf_centroids,
+                   idx.scale, idx.mean, idx.pca, store.vectors,
+                   store.norms_sq)
 
 
 # ---------------------------------------------------------------------------
@@ -231,7 +242,7 @@ def distributed_kmeans_fn(mesh: Mesh, axis: str, k: int, iters: int,
         cent, _ = jax.lax.scan(step, cent, None, length=iters)
         return cent
 
-    fn = compat.shard_map(local, mesh=mesh, in_specs=(P(axis), P()),
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P(axis), P()),
                        out_specs=P(), check_vma=False)
     return jax.jit(fn)
 
@@ -761,8 +772,10 @@ def sharded_graph_search_fn(graph: HNSWGraph, store: VectorStore,
                             use_pallas: bool = False):
     """The real-mesh path: the same shard body under `shard_map`.
 
-    Builds (or takes) a 1-D mesh over the first `num_shards` devices and
-    returns a jitted (queries, bitmaps) -> (dists, ids, SearchStats) fn.
+    Builds (or takes) a 1-D mesh over the first `num_shards` devices,
+    places shard s of every tier on device s, and returns
+    `partial(step, gviews, sviews)`: a (queries, bitmaps) -> (dists, ids,
+    SearchStats) fn whose placed tiers ride as jit arguments (`.args`).
     Validation twin of the vmap emulation — in lockstep mode both produce
     bit-identical results (tests/test_sharding.py runs this under
     --xla_force_host_platform_device_count)."""
@@ -772,7 +785,8 @@ def sharded_graph_search_fn(graph: HNSWGraph, store: VectorStore,
             raise ValueError(f"need {num_shards} devices for "
                              f"{num_shards} shards, have {len(devs)}")
         mesh = Mesh(np.asarray(devs[:num_shards]), (axis,))
-    gv, sv = shard_graph_tiers(graph, store, num_shards, axis=axis)
+    tiers = place_shards(shard_graph_tiers(graph, store, num_shards,
+                                           axis=axis), mesh, axis)
 
     def local(gstack, sstack, queries, bitmaps):
         g = _squeeze0(gstack)
@@ -780,8 +794,7 @@ def sharded_graph_search_fn(graph: HNSWGraph, store: VectorStore,
         return _graph_shard_body(g, s, queries, bitmaps, params,
                                  use_pallas, False)
 
-    fn = compat.shard_map(local, mesh=mesh,
-                          in_specs=(P(axis), P(axis), P(), P()),
-                          out_specs=(P(), P(), P()),
-                          check_vma=False)
-    return jax.jit(lambda q, b: fn(gv, sv, q, b))
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(axis), P(axis), P(), P()),
+                       out_specs=(P(), P(), P()), check_vma=False)
+    return partial(jax.jit(fn), *tiers)

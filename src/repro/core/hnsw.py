@@ -17,6 +17,8 @@ of `neighbors[l]` is one "index page access".
 from __future__ import annotations
 
 import dataclasses
+import os
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -147,6 +149,9 @@ def _components(level_nbrs: np.ndarray) -> np.ndarray:
     return np.array([find(i) for i in range(n)])
 
 
+_PRUNE_THREADS = 8
+
+
 def _diversity_prune(vectors: np.ndarray, cand_ids: np.ndarray,
                      cand_d: np.ndarray, m: int, metric: str,
                      block: int = 4096) -> np.ndarray:
@@ -154,44 +159,63 @@ def _diversity_prune(vectors: np.ndarray, cand_ids: np.ndarray,
 
     Keep candidate c (in increasing-distance order) iff it is closer to the
     node than to every already-kept neighbor.  Returns (n, m) ids, -1 padded.
+    Node blocks are independent and run on a thread pool (numpy releases
+    the interpreter lock in these kernels); the result does not depend on
+    the thread count.  Each running block holds about 250 MB at 4096
+    nodes, 48 candidates and d=128, so the pool is capped at
+    `_PRUNE_THREADS` whatever the host's core count.
     """
-    n, kc = cand_ids.shape
+    n = cand_ids.shape[0]
     out = np.full((n, m), -1, np.int64)
-    for s in range(0, n, block):
-        e = min(s + block, n)
-        cids = cand_ids[s:e]                       # (b, kc)
-        cvec = vectors[cids]                       # (b, kc, d)
-        # pairwise distances between candidates of the same node: (b, kc, kc)
-        if metric == "ip":
-            cc = -np.einsum("bid,bjd->bij", cvec, cvec)
-        elif metric == "cos":
-            cn = cvec / (np.linalg.norm(cvec, axis=2, keepdims=True) + 1e-12)
-            cc = 1.0 - np.einsum("bid,bjd->bij", cn, cn)
-        else:
-            sq = (cvec * cvec).sum(2)
-            cc = sq[:, :, None] + sq[:, None, :] - 2.0 * np.einsum(
-                "bid,bjd->bij", cvec, cvec)
-        kept = np.zeros((e - s, kc), bool)
-        kept_cnt = np.zeros(e - s, np.int64)
-        for j in range(kc):
-            d_to_node = cand_d[s:e, j]
-            # distance from candidate j to every kept candidate
-            d_to_kept = np.where(kept, cc[:, j, :], np.inf)
-            ok = (d_to_node < d_to_kept.min(axis=1)) & (kept_cnt < m)
-            kept[:, j] = ok
-            kept_cnt += ok
-        for b in range(e - s):
-            sel = list(cids[b, kept[b]][:m])
-            if len(sel) < m:
-                # keepPrunedConnections (standard HNSW): fill remaining
-                # slots with the closest pruned candidates.
-                for c in cids[b, ~kept[b]]:
-                    if len(sel) >= m:
-                        break
-                    if c not in sel:
-                        sel.append(c)
-            out[s + b, : len(sel)] = sel
+    starts = range(0, n, block)
+    workers = max(1, min(len(starts), len(os.sched_getaffinity(0)),
+                         _PRUNE_THREADS))
+    with ThreadPoolExecutor(max_workers=workers) as pool:
+        for s, sel in zip(starts, pool.map(
+                lambda s: _prune_block(vectors, cand_ids[s:s + block],
+                                       cand_d[s:s + block], m, metric),
+                starts)):
+            out[s:s + block, :sel.shape[1]] = sel
     return out
+
+
+def _prune_block(vectors: np.ndarray, cids: np.ndarray, cd: np.ndarray,
+                 m: int, metric: str) -> np.ndarray:
+    """`_diversity_prune` of one node block: (b, kc) candidates (ids and
+    distances to the node, ascending) -> (b, min(m, kc)) ids, -1 padded."""
+    b, kc = cids.shape
+    cvec = vectors[cids]                           # (b, kc, d)
+    # pairwise distances between candidates of the same node: (b, kc, kc)
+    if metric == "ip":
+        cc = -np.einsum("bid,bjd->bij", cvec, cvec)
+    elif metric == "cos":
+        cn = cvec / (np.linalg.norm(cvec, axis=2, keepdims=True) + 1e-12)
+        cc = 1.0 - np.einsum("bid,bjd->bij", cn, cn)
+    else:
+        sq = (cvec * cvec).sum(2)
+        cc = sq[:, :, None] + sq[:, None, :] - 2.0 * np.einsum(
+            "bid,bjd->bij", cvec, cvec)
+    kept = np.zeros((b, kc), bool)
+    kept_cnt = np.zeros(b, np.int64)
+    for j in range(kc):
+        # distance from candidate j to every kept candidate
+        d_to_kept = np.where(kept, cc[:, j, :], np.inf)
+        ok = (cd[:, j] < d_to_kept.min(axis=1)) & (kept_cnt < m)
+        kept[:, j] = ok
+        kept_cnt += ok
+    # keepPrunedConnections (standard HNSW): slots left free take the
+    # closest pruned candidates, skipping ids already selected.  Kept
+    # candidates come first, then the pruned ones in distance order.
+    same = cids[:, :, None] == cids[:, None, :]               # (b, kc, kc)
+    in_kept = (same & kept[:, None, :]).any(2)
+    earlier = np.tril(np.ones((kc, kc), bool), -1)            # [j, i]: i < j
+    repeat = (same & ~kept[:, None, :] & earlier).any(2)
+    fill = ~kept & ~in_kept & ~repeat
+    col = np.arange(kc)
+    key = np.where(kept, col, np.where(fill, kc + col, 2 * kc))
+    pick = np.argsort(key, axis=1, kind="stable")[:, :m]
+    return np.where(np.take_along_axis(key, pick, 1) < 2 * kc,
+                    np.take_along_axis(cids, pick, 1), -1)
 
 
 def build_graph(store: VectorStore, m: int = 16, ef_construction: int = 64,

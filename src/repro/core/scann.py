@@ -65,22 +65,45 @@ class ScannIndex:
         return self.leaf_tiles.shape[0]
 
 
+@partial(jax.jit, static_argnames=("n",))
+def _nearest_centroid(xb: jax.Array, cent: jax.Array, n: int) -> jax.Array:
+    """Index of the L2-nearest centroid of every row: xb (B, block, d)
+    row blocks (zero-padded past row n), cent (k, d) -> (n,) int32."""
+    cn = jnp.sum(cent * cent, axis=1)
+
+    def block(x):
+        ip = jnp.matmul(x, cent.T, precision=jax.lax.Precision.HIGHEST)
+        d = jnp.sum(x * x, axis=1, keepdims=True) + cn[None, :] - 2.0 * ip
+        return jnp.argmin(d, axis=1).astype(jnp.int32)
+
+    return jax.lax.map(block, xb).reshape(-1)[:n]
+
+
 def _kmeans(x: np.ndarray, k: int, iters: int = 12, seed: int = 0,
             block: int = 8192) -> tuple[np.ndarray, np.ndarray]:
-    """Plain Lloyd's. Returns (centroids (k, d), assignment (n,))."""
-    n = x.shape[0]
+    """Plain Lloyd's. Returns (centroids (k, d), assignment (n,)).
+
+    The assignment step, n·k distances per iteration, runs on the default
+    device in f32; the centroid update stays on the host."""
+    n, d = x.shape
     rng = np.random.RandomState(seed)
     cent = x[rng.choice(n, size=k, replace=False)].copy()
-    assign = np.zeros(n, np.int64)
+    block = min(block, n)
+    xb = jnp.asarray(np.pad(x, ((0, (-n) % block), (0, 0)))
+                     .reshape(-1, block, d), jnp.float32)
     for _ in range(iters):
-        for s in range(0, n, block):
-            e = min(s + block, n)
-            d = ((x[s:e] ** 2).sum(1)[:, None] + (cent ** 2).sum(1)[None, :]
-                 - 2.0 * x[s:e] @ cent.T)
-            assign[s:e] = d.argmin(1)
+        assign = np.asarray(_nearest_centroid(
+            xb, jnp.asarray(cent, jnp.float32), n), np.int64)
+        # per-cluster row sums, accumulated row by row in row order (the
+        # sums np.add.at gives, without its per-element dispatch)
+        cnt = np.bincount(assign, minlength=k)
+        order = np.argsort(assign, kind="stable")
+        starts = np.concatenate([[0], np.cumsum(cnt)[:-1]])
+        nz = np.flatnonzero(cnt)
         sums = np.zeros_like(cent)
-        np.add.at(sums, assign, x)
-        cnt = np.bincount(assign, minlength=k).astype(np.float64)
+        sums[nz] = np.add.reduceat(x[order], starts[nz], axis=0,
+                                   dtype=sums.dtype)
+        cnt = cnt.astype(np.float64)
         empty = cnt == 0
         cent = np.where(empty[:, None], cent,
                         sums / np.maximum(cnt, 1)[:, None])
@@ -117,12 +140,11 @@ def build_scann(store: VectorStore, num_leaves: int, levels: int = 2,
     cap = int(counts.max())
     cap += (-cap) % 8  # sublane alignment
     rowids = np.full((num_leaves, cap), -1, np.int64)
+    # rows of each leaf in ascending row order
     order = np.argsort(assign, kind="stable")
-    offs = np.zeros(num_leaves, np.int64)
-    for row in order:
-        a = assign[row]
-        rowids[a, offs[a]] = row
-        offs[a] += 1
+    leaf = assign[order]
+    starts = np.cumsum(counts) - counts
+    rowids[leaf, np.arange(n) - starts[leaf]] = order
 
     # SQ8: per-dimension affine quantization over the dataset (the shared
     # quantizer — the graph engine's shadow store uses the same one)
@@ -176,7 +198,8 @@ def _row_norms_sq(tiles: jax.Array, scale: jax.Array,
 def project_query(index: ScannIndex, q: jax.Array) -> jax.Array:
     """Apply the (folded-centering) PCA projection to a query."""
     proj, mu_p = index.pca[:-1], index.pca[-1]
-    return q @ proj - mu_p
+    return jnp.matmul(q, proj,
+                      precision=jax.lax.Precision.HIGHEST) - mu_p
 
 
 def _quant_pages_per_leaf(index: ScannIndex) -> int:

@@ -22,7 +22,9 @@ from jax.experimental import pallas as pl
 def _dist_kernel(q_ref, x_ref, out_ref, *, metric: str):
     q = q_ref[...]                       # (BQ, D) f32
     x = x_ref[...]                       # (BN, D) f32
-    ip = jnp.dot(q, x.T, preferred_element_type=jnp.float32)
+    ip = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32)
     if metric == "ip":
         out_ref[...] = -ip
     else:

@@ -1,4 +1,4 @@
-"""Fused frontier-scan Pallas kernel (graph engine superstep, DESIGN.md §7).
+"""Fused frontier-scan Pallas kernels (graph engine superstep, DESIGN.md §7).
 
 One superstep of the batch-synchronous graph engine scores, per in-flight
 query, a chunk of candidate nodes compacted out of the frontier's
@@ -9,34 +9,36 @@ frontier-union block (each distinct node is fetched from HBM once per
 superstep, however many queries touch it); this kernel fuses the remaining
 hot work in one VMEM-resident pass per query:
 
-  * distance of the query against its (C, d) candidate chunk — one
-    MXU-friendly (C, d) × (d,) contraction, plus the precomputed-norm L2
-    completion (the per-row ‖x‖² never recomputes inside the step);
-  * the packed-bitmap filter probe (one uint32 word gather per row — the
-    same batched-probe shape as the leaf-scan kernels).
+  * distance of the query against its (C, d) candidate chunk — one MXU
+    (1, d) × (d, C) contraction, plus the precomputed-norm L2 completion
+    (the per-row ‖x‖² never recomputes inside the step);
+  * the filter bit test.  The bitmap word of every candidate row,
+    `bitmaps[q, id >> 5]`, is gathered by XLA before the call and enters
+    as a (Q, C) uint32 block aligned with the ids; the kernel keeps only
+    the shift and the mask.  The kernel never sees the (Q, n/32) bitmap,
+    so its VMEM footprint does not grow with the table.
+
+Every per-query operand is laid out (Q, 1, X) and blocked (1, 1, X): the
+last two block dimensions equal the array's, which is what the TPU
+lowering requires of a block that is one row tall.
 
 Outputs are the raw distances (+inf only at id padding — strategies decide
 how filtering gates insertion, so the pass mask is returned separately as
-int8) — semantics mirrored exactly by `ref.frontier_scan_ref`, the jnp
-oracle the engine uses on non-TPU backends and the allclose target of the
-interpret-mode parity tests.
+int8) — semantics mirrored by `ref.frontier_scan_ref`, the jnp oracle the
+engine uses by default and the allclose target of the parity tests (the
+pass mask is bit-identical).
 
-VMEM envelope per grid step (f32): query d + chunk C×d + norms/ids/out C
-+ bitmap W words.  For C=128, d=1024: 0.5 MB chunk — far inside v5e's
-16 MB/core, leaving the double-buffered prefetch of the next query's
-chunk free (the grid walks queries, so the union block's rows stream
-HBM→VMEM at most once per appearance in a chunk).
+Variants, all one kernel body:
+  * `frontier_scan_sq8_pallas` (DESIGN.md §9): the chunk arrives as SQ8
+    int8 rows straight from the shadow heap — 4× less HBM→VMEM traffic
+    per candidate — and is dequantized in-kernel (x = t·scale + mean)
+    before the same contraction; ‖x̂‖² is precomputed at quantization.
+  * `frontier_scan_excl[_sq8]_pallas` (DESIGN.md §14): a third output,
+    the FAVOR keep mask, computed by the same `excl_keep_mask` ops as the
+    oracle so it is bit-identical given equal distances.
 
-`frontier_scan_sq8_pallas` is the quantized-traversal variant
-(DESIGN.md §9): the chunk arrives as SQ8 int8 rows straight from the
-shadow heap — 4× less HBM→VMEM traffic per candidate — and is
-dequantized IN-KERNEL (x = t·scale + mean, the `leaf_scan` fusion math)
-before the same contraction + bitmap probe.  Per-row ‖x̂‖² of the
-dequantized rows is precomputed at quantization time and streamed in,
-so the L2 completion never re-reduces inside the step.  VMEM per grid
-step: C×d int8 chunk + C×d f32 dequant + 2×d scale/mean — for C=128,
-d=1024: 0.64 MB.  int8 blocks obey the (32, 128) min-tile; C pads to
-the 128-lane output axis, satisfying both.
+VMEM per grid step (f32 chunk): query d + chunk C×d + five C-wide rows;
+for C=128, d=1536: 0.8 MB, double-buffered well inside v5e's 16 MB.
 """
 from __future__ import annotations
 
@@ -48,26 +50,98 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.ref import excl_keep_mask
 
+_NT = (((1,), (1,)), ((), ()))          # contract the last dim of both sides
 
-def _frontier_scan_kernel(q_ref, vec_ref, norm_ref, id_ref, bitmap_ref,
-                          dist_ref, pass_ref, *, metric: str):
-    q = q_ref[...][0]                                # (d,) f32
-    x = vec_ref[...][0]                              # (C, d) f32
-    xn = norm_ref[...][0]                            # (C,) f32
-    rid = id_ref[...][0]                             # (C,) int32
-    ip = jnp.dot(x, q, preferred_element_type=jnp.float32)     # (C,)
+
+def probe_words(bitmaps: jax.Array, ids: jax.Array) -> jax.Array:
+    """(Q, W) packed bitmaps, (Q, C) row ids -> (Q, C) uint32: the word
+    holding each row's bit (row 0's word at padding ids)."""
+    return jnp.take_along_axis(bitmaps, jnp.maximum(ids, 0) >> 5, axis=1)
+
+
+def bit_pass(words, rid):
+    """The in-kernel half of the bitmap probe: shift + mask + id validity."""
+    shift = (jnp.maximum(rid, 0) & 31).astype(jnp.uint32)
+    return (((words >> shift) & jnp.uint32(1)) == 1) & (rid >= 0)
+
+
+def _frontier_kernel(*refs, metric: str, sq8: bool, excl: bool,
+                     margin: float):
+    refs = list(refs)
+    q_ref, vec_ref = refs.pop(0), refs.pop(0)
+    scale_ref, mean_ref = (refs.pop(0), refs.pop(0)) if sq8 else (None, None)
+    norm_ref, id_ref, word_ref = refs.pop(0), refs.pop(0), refs.pop(0)
+    excl_ref, tau_ref = (refs.pop(0), refs.pop(0)) if excl else (None, None)
+    dist_ref, pass_ref = refs.pop(0), refs.pop(0)
+    keep_ref = refs.pop(0) if excl else None
+
+    q = q_ref[0]                                     # (1, d) f32
+    x = vec_ref[0]                                   # (C, d)
+    if sq8:
+        x = x.astype(jnp.float32) * scale_ref[...] + mean_ref[...]
+    ip = jax.lax.dot_general(q, x, _NT, precision=jax.lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32)  # (1, C)
     if metric == "ip":
         d = -ip
     else:
-        qn = jnp.sum(q * q)
-        d = qn + xn - 2.0 * ip
-    safe = jnp.maximum(rid, 0)
-    words = bitmap_ref[...][0]                       # (W,) uint32
-    w = jnp.take(words, safe >> 5, axis=0)
-    bit = (w >> (safe & 31).astype(jnp.uint32)) & jnp.uint32(1)
-    ok = (bit == 1) & (rid >= 0)
-    dist_ref[...] = jnp.where(rid >= 0, d, jnp.inf)[None, :]
-    pass_ref[...] = ok.astype(jnp.int8)[None, :]
+        qn = jnp.sum(q * q, axis=1, keepdims=True)   # (1, 1)
+        d = qn + norm_ref[0] - 2.0 * ip
+    rid = id_ref[0]                                  # (1, C) int32
+    ok = bit_pass(word_ref[0], rid)
+    dfin = jnp.where(rid >= 0, d, jnp.inf)
+    dist_ref[0] = dfin
+    pass_ref[0] = ok.astype(jnp.int8)
+    if excl:
+        keep = excl_keep_mask(dfin, excl_ref[0], tau_ref[0], ok, margin)
+        keep_ref[0] = keep.astype(jnp.int8)
+
+
+def _frontier_call(queries, vecs, norms, ids, bitmaps, *, metric: str,
+                   interpret: bool, scale=None, mean=None, excl=None,
+                   tau=None, margin: float = 0.5):
+    """Pad to (8·, 128·) tiles, lay per-query operands out as (Q, 1, X),
+    gather the probe words, and run the grid-(Q,) kernel."""
+    sq8, has_excl = scale is not None, excl is not None
+    nq, c, d = vecs.shape
+    pd = (-d) % 128
+    pc = (-c) % 128          # C is the lane axis of the (1, C) rows
+    cp, dp = c + pc, d + pd
+
+    def row(a):              # (Q, C) -> (Q, 1, Cp)
+        return jnp.pad(a, ((0, 0), (0, pc)))[:, None]
+
+    idp = jnp.pad(ids, ((0, 0), (0, pc)), constant_values=-1)
+    args = [jnp.pad(queries.astype(jnp.float32), ((0, 0), (0, pd)))[:, None],
+            jnp.pad(vecs if sq8 else vecs.astype(jnp.float32),
+                    ((0, 0), (0, pc), (0, pd)))]
+    per_q_row = pl.BlockSpec((1, 1, cp), lambda i: (i, 0, 0))
+    specs = [pl.BlockSpec((1, 1, dp), lambda i: (i, 0, 0)),
+             pl.BlockSpec((1, cp, dp), lambda i: (i, 0, 0))]
+    if sq8:
+        for v in (scale, mean):
+            args.append(jnp.pad(v.astype(jnp.float32), (0, pd))[None, :])
+            specs.append(pl.BlockSpec((1, dp), lambda i: (0, 0)))
+    args += [row(norms.astype(jnp.float32)), idp[:, None],
+             probe_words(bitmaps, idp)[:, None]]
+    specs += [per_q_row] * 3
+    if has_excl:
+        args += [row(excl.astype(jnp.float32)),
+                 tau.astype(jnp.float32).reshape(nq, 1, 1)]
+        specs += [per_q_row, pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0))]
+    n_out = 3 if has_excl else 2
+    outs = pl.pallas_call(
+        functools.partial(_frontier_kernel, metric=metric, sq8=sq8,
+                          excl=has_excl, margin=margin),
+        grid=(nq,),
+        in_specs=specs,
+        out_specs=[per_q_row] * n_out,
+        out_shape=[jax.ShapeDtypeStruct((nq, 1, cp), jnp.float32)]
+        + [jax.ShapeDtypeStruct((nq, 1, cp), jnp.int8)] * (n_out - 1),
+        interpret=interpret,
+    )(*args)
+    dist = outs[0][:, 0, :c]
+    masks = [m[:, 0, :c].astype(bool) for m in outs[1:]]
+    return (dist, *masks)
 
 
 def frontier_scan_pallas(queries: jax.Array, vecs: jax.Array,
@@ -78,63 +152,9 @@ def frontier_scan_pallas(queries: jax.Array, vecs: jax.Array,
     """queries (Q, d), vecs (Q, C, d) f32, norms (Q, C), ids (Q, C) int32,
     bitmaps (Q, W) uint32 → (dists (Q, C) f32, pass (Q, C) bool).
 
-    Grid is (Q,): one step fuses one query's chunk scoring + filter probe.
-    """
-    nq, c, d = vecs.shape
-    w = bitmaps.shape[1]
-    pd = (-d) % 128
-    pc = (-c) % 128          # C is the lane axis of the (1, C) outputs
-    q = jnp.pad(queries.astype(jnp.float32), ((0, 0), (0, pd)))
-    v = jnp.pad(vecs.astype(jnp.float32), ((0, 0), (0, pc), (0, pd)))
-    nrm = jnp.pad(norms.astype(jnp.float32), ((0, 0), (0, pc)))
-    idp = jnp.pad(ids, ((0, 0), (0, pc)), constant_values=-1)
-    cp, dp = c + pc, d + pd
-    dist, ok = pl.pallas_call(
-        functools.partial(_frontier_scan_kernel, metric=metric),
-        grid=(nq,),
-        in_specs=[
-            pl.BlockSpec((1, dp), lambda i: (i, 0)),          # query
-            pl.BlockSpec((1, cp, dp), lambda i: (i, 0, 0)),   # chunk vecs
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),          # row norms
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),          # row ids
-            pl.BlockSpec((1, w), lambda i: (i, 0)),           # bitmap
-        ],
-        out_specs=[
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, cp), jnp.float32),
-            jax.ShapeDtypeStruct((nq, cp), jnp.int8),
-        ],
-        interpret=interpret,
-    )(q, v, nrm, idp, bitmaps)
-    return dist[:, :c], ok[:, :c].astype(bool)
-
-
-def _frontier_scan_sq8_kernel(q_ref, vec_ref, scale_ref, mean_ref, norm_ref,
-                              id_ref, bitmap_ref, dist_ref, pass_ref, *,
-                              metric: str):
-    q = q_ref[...][0]                                # (d,) f32
-    t = vec_ref[...][0]                              # (C, d) int8
-    scale = scale_ref[...]                           # (1, d) f32
-    mean = mean_ref[...]                             # (1, d) f32
-    xn = norm_ref[...][0]                            # (C,) f32 ||x̂||²
-    rid = id_ref[...][0]                             # (C,) int32
-    x = t.astype(jnp.float32) * scale + mean         # in-kernel dequant
-    ip = jnp.dot(x, q, preferred_element_type=jnp.float32)     # (C,)
-    if metric == "ip":
-        d = -ip
-    else:
-        qn = jnp.sum(q * q)
-        d = qn + xn - 2.0 * ip
-    safe = jnp.maximum(rid, 0)
-    words = bitmap_ref[...][0]                       # (W,) uint32
-    w = jnp.take(words, safe >> 5, axis=0)
-    bit = (w >> (safe & 31).astype(jnp.uint32)) & jnp.uint32(1)
-    ok = (bit == 1) & (rid >= 0)
-    dist_ref[...] = jnp.where(rid >= 0, d, jnp.inf)[None, :]
-    pass_ref[...] = ok.astype(jnp.int8)[None, :]
+    Grid is (Q,): one step fuses one query's chunk scoring + bit test."""
+    return _frontier_call(queries, vecs, norms, ids, bitmaps, metric=metric,
+                          interpret=interpret)
 
 
 def frontier_scan_sq8_pallas(queries: jax.Array, qvecs: jax.Array,
@@ -150,67 +170,8 @@ def frontier_scan_sq8_pallas(queries: jax.Array, qvecs: jax.Array,
 
     Same grid/fusion as `frontier_scan_pallas`, with the dequantization
     folded into the kernel so only int8 rows cross HBM→VMEM."""
-    nq, c, d = qvecs.shape
-    w = bitmaps.shape[1]
-    pd = (-d) % 128
-    pc = (-c) % 128          # C is the lane axis of the (1, C) outputs
-    q = jnp.pad(queries.astype(jnp.float32), ((0, 0), (0, pd)))
-    v = jnp.pad(qvecs, ((0, 0), (0, pc), (0, pd)))
-    s = jnp.pad(scale.astype(jnp.float32), (0, pd))[None, :]
-    m = jnp.pad(mean.astype(jnp.float32), (0, pd))[None, :]
-    nrm = jnp.pad(norms.astype(jnp.float32), ((0, 0), (0, pc)))
-    idp = jnp.pad(ids, ((0, 0), (0, pc)), constant_values=-1)
-    cp, dp = c + pc, d + pd
-    dist, ok = pl.pallas_call(
-        functools.partial(_frontier_scan_sq8_kernel, metric=metric),
-        grid=(nq,),
-        in_specs=[
-            pl.BlockSpec((1, dp), lambda i: (i, 0)),          # query
-            pl.BlockSpec((1, cp, dp), lambda i: (i, 0, 0)),   # int8 chunk
-            pl.BlockSpec((1, dp), lambda i: (0, 0)),          # scale
-            pl.BlockSpec((1, dp), lambda i: (0, 0)),          # mean
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),          # dequant norms
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),          # row ids
-            pl.BlockSpec((1, w), lambda i: (i, 0)),           # bitmap
-        ],
-        out_specs=[
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, cp), jnp.float32),
-            jax.ShapeDtypeStruct((nq, cp), jnp.int8),
-        ],
-        interpret=interpret,
-    )(q, v, s, m, nrm, idp, bitmaps)
-    return dist[:, :c], ok[:, :c].astype(bool)
-
-
-def _frontier_scan_excl_kernel(q_ref, vec_ref, norm_ref, id_ref, bitmap_ref,
-                               excl_ref, tau_ref, dist_ref, pass_ref,
-                               keep_ref, *, metric: str, margin: float):
-    q = q_ref[...][0]                                # (d,) f32
-    x = vec_ref[...][0]                              # (C, d) f32
-    xn = norm_ref[...][0]                            # (C,) f32
-    rid = id_ref[...][0]                             # (C,) int32
-    ip = jnp.dot(x, q, preferred_element_type=jnp.float32)     # (C,)
-    if metric == "ip":
-        d = -ip
-    else:
-        qn = jnp.sum(q * q)
-        d = qn + xn - 2.0 * ip
-    safe = jnp.maximum(rid, 0)
-    words = bitmap_ref[...][0]                       # (W,) uint32
-    w = jnp.take(words, safe >> 5, axis=0)
-    bit = (w >> (safe & 31).astype(jnp.uint32)) & jnp.uint32(1)
-    ok = (bit == 1) & (rid >= 0)
-    dfin = jnp.where(rid >= 0, d, jnp.inf)
-    e = excl_ref[...][0]                             # (C,) f32 radii
-    tau = tau_ref[0, 0]                              # scalar W tail
-    keep = excl_keep_mask(dfin, e, tau, ok, margin)
-    dist_ref[...] = dfin[None, :]
-    pass_ref[...] = ok.astype(jnp.int8)[None, :]
-    keep_ref[...] = keep.astype(jnp.int8)[None, :]
+    return _frontier_call(queries, qvecs, norms, ids, bitmaps, metric=metric,
+                          interpret=interpret, scale=scale, mean=mean)
 
 
 def frontier_scan_excl_pallas(queries: jax.Array, vecs: jax.Array,
@@ -224,76 +185,12 @@ def frontier_scan_excl_pallas(queries: jax.Array, vecs: jax.Array,
     Extra inputs: excl (Q, C) f32 squared exclusion radii of the chunk
     rows (gathered alongside the vectors — zero extra HBM round trips)
     and tau (Q, 1) f32 per-query W tail.  Third output: keep (Q, C) bool,
-    computed by the SAME `excl_keep_mask` ops as the jnp oracle so the
-    mask is bit-identical across paths.  dists/pass semantics unchanged.
+    computed by the SAME `excl_keep_mask` ops as the jnp oracle.
+    dists/pass semantics unchanged.
     """
-    nq, c, d = vecs.shape
-    w = bitmaps.shape[1]
-    pd = (-d) % 128
-    pc = (-c) % 128          # C is the lane axis of the (1, C) outputs
-    q = jnp.pad(queries.astype(jnp.float32), ((0, 0), (0, pd)))
-    v = jnp.pad(vecs.astype(jnp.float32), ((0, 0), (0, pc), (0, pd)))
-    nrm = jnp.pad(norms.astype(jnp.float32), ((0, 0), (0, pc)))
-    idp = jnp.pad(ids, ((0, 0), (0, pc)), constant_values=-1)
-    ex = jnp.pad(excl.astype(jnp.float32), ((0, 0), (0, pc)))
-    cp, dp = c + pc, d + pd
-    dist, ok, keep = pl.pallas_call(
-        functools.partial(_frontier_scan_excl_kernel, metric=metric,
-                          margin=margin),
-        grid=(nq,),
-        in_specs=[
-            pl.BlockSpec((1, dp), lambda i: (i, 0)),          # query
-            pl.BlockSpec((1, cp, dp), lambda i: (i, 0, 0)),   # chunk vecs
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),          # row norms
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),          # row ids
-            pl.BlockSpec((1, w), lambda i: (i, 0)),           # bitmap
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),          # excl radii
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),           # W tail
-        ],
-        out_specs=[
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, cp), jnp.float32),
-            jax.ShapeDtypeStruct((nq, cp), jnp.int8),
-            jax.ShapeDtypeStruct((nq, cp), jnp.int8),
-        ],
-        interpret=interpret,
-    )(q, v, nrm, idp, bitmaps, ex, tau.astype(jnp.float32))
-    return dist[:, :c], ok[:, :c].astype(bool), keep[:, :c].astype(bool)
-
-
-def _frontier_scan_excl_sq8_kernel(q_ref, vec_ref, scale_ref, mean_ref,
-                                   norm_ref, id_ref, bitmap_ref, excl_ref,
-                                   tau_ref, dist_ref, pass_ref, keep_ref, *,
-                                   metric: str, margin: float):
-    q = q_ref[...][0]                                # (d,) f32
-    t = vec_ref[...][0]                              # (C, d) int8
-    scale = scale_ref[...]                           # (1, d) f32
-    mean = mean_ref[...]                             # (1, d) f32
-    xn = norm_ref[...][0]                            # (C,) f32 ||x̂||²
-    rid = id_ref[...][0]                             # (C,) int32
-    x = t.astype(jnp.float32) * scale + mean         # in-kernel dequant
-    ip = jnp.dot(x, q, preferred_element_type=jnp.float32)     # (C,)
-    if metric == "ip":
-        d = -ip
-    else:
-        qn = jnp.sum(q * q)
-        d = qn + xn - 2.0 * ip
-    safe = jnp.maximum(rid, 0)
-    words = bitmap_ref[...][0]                       # (W,) uint32
-    w = jnp.take(words, safe >> 5, axis=0)
-    bit = (w >> (safe & 31).astype(jnp.uint32)) & jnp.uint32(1)
-    ok = (bit == 1) & (rid >= 0)
-    dfin = jnp.where(rid >= 0, d, jnp.inf)
-    e = excl_ref[...][0]                             # (C,) f32 radii
-    tau = tau_ref[0, 0]                              # scalar W tail
-    keep = excl_keep_mask(dfin, e, tau, ok, margin)
-    dist_ref[...] = dfin[None, :]
-    pass_ref[...] = ok.astype(jnp.int8)[None, :]
-    keep_ref[...] = keep.astype(jnp.int8)[None, :]
+    return _frontier_call(queries, vecs, norms, ids, bitmaps, metric=metric,
+                          interpret=interpret, excl=excl, tau=tau,
+                          margin=margin)
 
 
 def frontier_scan_excl_sq8_pallas(queries: jax.Array, qvecs: jax.Array,
@@ -307,43 +204,6 @@ def frontier_scan_excl_sq8_pallas(queries: jax.Array, qvecs: jax.Array,
     """`frontier_scan_sq8_pallas` + the fused FAVOR keep mask: int8 chunk
     rows dequantized in-kernel, keep rule applied to the quantized
     distances (the distances pool insertion uses)."""
-    nq, c, d = qvecs.shape
-    w = bitmaps.shape[1]
-    pd = (-d) % 128
-    pc = (-c) % 128          # C is the lane axis of the (1, C) outputs
-    q = jnp.pad(queries.astype(jnp.float32), ((0, 0), (0, pd)))
-    v = jnp.pad(qvecs, ((0, 0), (0, pc), (0, pd)))
-    s = jnp.pad(scale.astype(jnp.float32), (0, pd))[None, :]
-    m = jnp.pad(mean.astype(jnp.float32), (0, pd))[None, :]
-    nrm = jnp.pad(norms.astype(jnp.float32), ((0, 0), (0, pc)))
-    idp = jnp.pad(ids, ((0, 0), (0, pc)), constant_values=-1)
-    ex = jnp.pad(excl.astype(jnp.float32), ((0, 0), (0, pc)))
-    cp, dp = c + pc, d + pd
-    dist, ok, keep = pl.pallas_call(
-        functools.partial(_frontier_scan_excl_sq8_kernel, metric=metric,
-                          margin=margin),
-        grid=(nq,),
-        in_specs=[
-            pl.BlockSpec((1, dp), lambda i: (i, 0)),          # query
-            pl.BlockSpec((1, cp, dp), lambda i: (i, 0, 0)),   # int8 chunk
-            pl.BlockSpec((1, dp), lambda i: (0, 0)),          # scale
-            pl.BlockSpec((1, dp), lambda i: (0, 0)),          # mean
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),          # dequant norms
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),          # row ids
-            pl.BlockSpec((1, w), lambda i: (i, 0)),           # bitmap
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),          # excl radii
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),           # W tail
-        ],
-        out_specs=[
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, cp), jnp.float32),
-            jax.ShapeDtypeStruct((nq, cp), jnp.int8),
-            jax.ShapeDtypeStruct((nq, cp), jnp.int8),
-        ],
-        interpret=interpret,
-    )(q, v, s, m, nrm, idp, bitmaps, ex, tau.astype(jnp.float32))
-    return dist[:, :c], ok[:, :c].astype(bool), keep[:, :c].astype(bool)
+    return _frontier_call(queries, qvecs, norms, ids, bitmaps, metric=metric,
+                          interpret=interpret, scale=scale, mean=mean,
+                          excl=excl, tau=tau, margin=margin)

@@ -2,36 +2,32 @@
 
 Two variants share the layout:
 
-`leaf_scan_pallas` — one grid step processes one ScaNN leaf for ONE query:
-the int8 tile is DMA'd HBM→VMEM (the TPU analogue of the paper's sequential
-leaf-page walk), rows are filter-checked against the packed bitmap (batched
-probe — the paper's §6.2.3(iii) SIMD advantage), dequantized, and scored
-against the query in a single VMEM-resident pass.  Filtered-out and padded
-rows emit +inf.
+`leaf_scan_pallas` — one grid step processes one row tile of one ScaNN
+leaf for ONE query: the int8 tile is DMA'd HBM→VMEM (the TPU analogue of
+the paper's sequential leaf-page walk), dequantized, scored against the
+query, and filter-tested, in a single VMEM-resident pass.  Filtered-out
+and padded rows emit +inf.
 
 `leaf_scan_batched_pallas` — the query-batched pipeline (DESIGN.md §4): one
-grid step DMAs one int8 leaf tile into VMEM ONCE and scores it against the
-whole query block via a single MXU (Q, d) × (d, C) contraction (the
-transpose of the (C, d) × (d, Q) form — same contraction, friendlier
-padding: Q rides the 8-sublane axis, C the 128-lane axis).  Per-query
-packed bitmaps are probed with one word-gather per (query, row) and
-precomputed row norms replace the per-query ||x||² reduction of the single
-query kernel.  This is what amortizes leaf fetch + filter + score across a
-concurrent query batch, instead of re-streaming every tile per query under
-`jax.vmap`.
+grid step DMAs one int8 leaf row tile into VMEM ONCE and scores it against
+the whole query block via a single MXU (Q, d) × (d, C) contraction (Q rides
+the 8-sublane axis, C the 128-lane axis).  Precomputed row norms replace
+the per-query ||x||² reduction of the single-query kernel.  This is what
+amortizes leaf fetch + filter + score across a concurrent query batch,
+instead of re-streaming every tile per query under `jax.vmap`.
 
-Fusion rationale (DESIGN.md §3): in an unfused pipeline the f32 dequantized
-tile and the boolean mask each round-trip through HBM; fusing keeps the
-working set at (C × d) int8 + (C × d) f32 in VMEM and streams the bitmap
-words once.  With C=512, d=1024: 0.5 MB int8 + 2 MB f32 — comfortably
-inside the 16 MB/core VMEM envelope of v5e, MXU-aligned (C, d multiples of
-8/128 after padding).  VMEM budget math for the batched tile is in
-DESIGN.md §4.
+The grid walks (leaf, row tile): rows are padded to a multiple of the
+tile height `_TILE_ROWS` (or of 128 below it), so one step's working set
+is (T × d) int8 + (T × d) f32 whatever the leaf capacity — 0.75 MB + 3 MB
+at T=512, d=1536, inside the 16 MB/core VMEM of v5e.
 
-The bitmap probe uses a gather of one uint32 word per row.  On TPU this
-lowers to a dynamic-slice loop over the (small) rowid vector — cheap next to
-the (C × d) contraction; correctness is validated in interpret mode against
-ref.leaf_scan_ref / ref.leaf_scan_batched_ref.
+Bitmap probe: XLA gathers the word holding each row's bit
+(`bitmap[rowid >> 5]`, per query for the batched kernel) before the call,
+into a uint32 block shaped like the scores; the kernel keeps only the
+shift and the mask (`frontier_scan.bit_pass`).  So the kernel never holds
+the n/32-word bitmap, and its VMEM does not grow with the table.  The
+pass mask is bit-identical to `ref.leaf_scan_ref` /
+`ref.leaf_scan_batched_ref`; the distances agree to f32 rounding.
 """
 from __future__ import annotations
 
@@ -41,29 +37,40 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.frontier_scan import _NT, bit_pass
+
+_TILE_ROWS = 512
+
+
+def _row_tiling(c: int) -> tuple[int, int]:
+    """(padded rows, tile height) for a leaf of capacity c."""
+    cp = c + (-c) % 128
+    tc = min(cp, _TILE_ROWS)
+    return cp + (-cp) % tc, tc
+
+
+def _dequant_scores(q, t, scale, mean, norms, metric: str):
+    """(Qb, d) queries against one (T, d) int8 row tile -> (Qb, T)."""
+    x = t.astype(jnp.float32) * scale + mean
+    ip = jax.lax.dot_general(q, x, _NT, precision=jax.lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32)
+    if metric == "ip":
+        return -ip
+    if norms is None:        # single-query kernel: reduce the resident tile
+        ones = jnp.ones((1, x.shape[1]), jnp.float32)
+        norms = jax.lax.dot_general(ones, x * x, _NT,
+                                    precision=jax.lax.Precision.HIGHEST,
+                                    preferred_element_type=jnp.float32)
+    qn = jnp.sum(q * q, axis=1, keepdims=True)
+    return qn + norms - 2.0 * ip
+
 
 def _leaf_scan_kernel(q_ref, tile_ref, rowid_ref, scale_ref, mean_ref,
-                      bitmap_ref, out_ref, *, metric: str):
-    q = q_ref[...]                                   # (1, d) f32
-    t = tile_ref[...][0]                             # (C, d) int8
-    rid = rowid_ref[...][0]                          # (C,) int32
-    scale = scale_ref[...]                           # (1, d)
-    mean = mean_ref[...]                             # (1, d)
-    x = t.astype(jnp.float32) * scale + mean         # dequant (C, d)
-    ip = jnp.dot(x, q[0], preferred_element_type=jnp.float32)  # (C,)
-    if metric == "ip":
-        d = -ip
-    else:
-        qn = jnp.sum(q[0] * q[0])
-        xn = jnp.sum(x * x, axis=-1)
-        d = qn + xn - 2.0 * ip
-    # batched bitmap probe
-    safe = jnp.maximum(rid, 0)
-    words = bitmap_ref[...][0]                       # (W,) uint32
-    w = jnp.take(words, safe >> 5, axis=0)
-    bit = (w >> (safe & 31).astype(jnp.uint32)) & jnp.uint32(1)
-    ok = (bit == 1) & (rid >= 0)
-    out_ref[...] = jnp.where(ok, d, jnp.inf)[None, :]
+                      word_ref, out_ref, *, metric: str):
+    d = _dequant_scores(q_ref[...], tile_ref[0], scale_ref[...],
+                        mean_ref[...], None, metric)             # (1, T)
+    ok = bit_pass(word_ref[0], rowid_ref[0])
+    out_ref[0] = jnp.where(ok, d, jnp.inf)
 
 
 def leaf_scan_pallas(query: jax.Array, tiles: jax.Array, rowids: jax.Array,
@@ -73,56 +80,41 @@ def leaf_scan_pallas(query: jax.Array, tiles: jax.Array, rowids: jax.Array,
     bitmap (W,) uint32 → scores (nl, C) f32 (+inf = filtered/padded)."""
     nl, c, d = tiles.shape
     pd = (-d) % 128
-    pc = (-c) % 8
-    tiles_p = jnp.pad(tiles, ((0, 0), (0, pc), (0, pd)))
-    rowids_p = jnp.pad(rowids, ((0, 0), (0, pc)), constant_values=-1)
+    cp, tc = _row_tiling(c)
+    tiles_p = jnp.pad(tiles, ((0, 0), (0, cp - c), (0, pd)))
+    rowids_p = jnp.pad(rowids, ((0, 0), (0, cp - c)), constant_values=-1)
+    words = bitmap[jnp.maximum(rowids_p, 0) >> 5]
     q = jnp.pad(query.astype(jnp.float32), (0, pd))[None, :]
     s = jnp.pad(scale.astype(jnp.float32), (0, pd))[None, :]
     m = jnp.pad(mean.astype(jnp.float32), (0, pd))[None, :]
-    bm = bitmap[None, :]
-    cp, dp = c + pc, d + pd
+    dp = d + pd
+    row_tile = pl.BlockSpec((1, 1, tc), lambda i, j: (i, 0, j))
     out = pl.pallas_call(
         functools.partial(_leaf_scan_kernel, metric=metric),
-        grid=(nl,),
+        grid=(nl, cp // tc),
         in_specs=[
-            pl.BlockSpec((1, dp), lambda i: (0, 0)),          # query
-            pl.BlockSpec((1, cp, dp), lambda i: (i, 0, 0)),   # leaf tile
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),          # rowids
-            pl.BlockSpec((1, dp), lambda i: (0, 0)),          # scale
-            pl.BlockSpec((1, dp), lambda i: (0, 0)),          # mean
-            pl.BlockSpec((1, bitmap.shape[0]), lambda i: (0, 0)),  # bitmap
+            pl.BlockSpec((1, dp), lambda i, j: (0, 0)),          # query
+            pl.BlockSpec((1, tc, dp), lambda i, j: (i, j, 0)),   # leaf rows
+            row_tile,                                            # rowids
+            pl.BlockSpec((1, dp), lambda i, j: (0, 0)),          # scale
+            pl.BlockSpec((1, dp), lambda i, j: (0, 0)),          # mean
+            row_tile,                                            # words
         ],
-        out_specs=pl.BlockSpec((1, cp), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nl, cp), jnp.float32),
+        out_specs=row_tile,
+        out_shape=jax.ShapeDtypeStruct((nl, 1, cp), jnp.float32),
         interpret=interpret,
-    )(q, tiles_p, rowids_p, s, m, bm)
-    return out[:, :c]
+    )(q, tiles_p, rowids_p[:, None], s, m, words[:, None])
+    return out[:, 0, :c]
 
 
 def _leaf_scan_batched_kernel(q_ref, tile_ref, rowid_ref, scale_ref,
-                              mean_ref, norms_ref, bitmap_ref, out_ref, *,
+                              mean_ref, norms_ref, word_ref, out_ref, *,
                               metric: str):
-    q = q_ref[...]                                   # (Qp, d) f32
-    t = tile_ref[...][0]                             # (C, d) int8
-    rid = rowid_ref[...][0]                          # (C,) int32
-    scale = scale_ref[...]                           # (1, d)
-    mean = mean_ref[...]                             # (1, d)
-    x = t.astype(jnp.float32) * scale + mean         # dequant (C, d)
     # MXU: score the whole query block against the resident tile at once
-    ip = jnp.dot(q, x.T, preferred_element_type=jnp.float32)   # (Qp, C)
-    if metric == "ip":
-        d = -ip
-    else:
-        xn = norms_ref[...][0]                       # (C,) precomputed ||x||²
-        qn = jnp.sum(q * q, axis=1, keepdims=True)   # (Qp, 1)
-        d = qn + xn[None, :] - 2.0 * ip
-    # per-query batched bitmap probe: one word gather per (query, row)
-    safe = jnp.maximum(rid, 0)
-    words = bitmap_ref[...]                          # (Qp, W) uint32
-    w = jnp.take(words, safe >> 5, axis=1)           # (Qp, C)
-    bit = (w >> (safe & 31).astype(jnp.uint32)[None, :]) & jnp.uint32(1)
-    ok = (bit == 1) & (rid >= 0)[None, :]
-    out_ref[...] = jnp.where(ok, d, jnp.inf)[None]
+    d = _dequant_scores(q_ref[...], tile_ref[0], scale_ref[...],
+                        mean_ref[...], norms_ref[0], metric)     # (Qp, T)
+    ok = bit_pass(word_ref[0], rowid_ref[0])
+    out_ref[0] = jnp.where(ok, d, jnp.inf)
 
 
 def leaf_scan_batched_pallas(queries: jax.Array, tiles: jax.Array,
@@ -134,35 +126,39 @@ def leaf_scan_batched_pallas(queries: jax.Array, tiles: jax.Array,
     scale/mean (d,) f32, bitmaps (Q, W) uint32, row_norms_sq (U, C) f32
     → scores (Q, U, C) f32 (+inf = filtered/padded).
 
-    Grid is (U,): each step fetches one leaf tile once and scores the whole
-    query batch against it (DESIGN.md §4)."""
+    Grid is (U, row tiles): each step fetches one leaf row tile once and
+    scores the whole query batch against it (DESIGN.md §4)."""
     u, c, d = tiles.shape
     nq = queries.shape[0]
     pd = (-d) % 128
-    pc = (-c) % 128          # C is the lane axis of the (Qp, C) output
     pq = (-nq) % 8
-    tiles_p = jnp.pad(tiles, ((0, 0), (0, pc), (0, pd)))
-    rowids_p = jnp.pad(rowids, ((0, 0), (0, pc)), constant_values=-1)
-    norms_p = jnp.pad(row_norms_sq.astype(jnp.float32), ((0, 0), (0, pc)))
+    cp, tc = _row_tiling(c)
+    tiles_p = jnp.pad(tiles, ((0, 0), (0, cp - c), (0, pd)))
+    rowids_p = jnp.pad(rowids, ((0, 0), (0, cp - c)), constant_values=-1)
+    norms_p = jnp.pad(row_norms_sq.astype(jnp.float32), ((0, 0), (0, cp - c)))
     q = jnp.pad(queries.astype(jnp.float32), ((0, pq), (0, pd)))
     s = jnp.pad(scale.astype(jnp.float32), (0, pd))[None, :]
     m = jnp.pad(mean.astype(jnp.float32), (0, pd))[None, :]
     bm = jnp.pad(bitmaps, ((0, pq), (0, 0)))         # padded queries: all 0
-    qp, cp, dp, w = nq + pq, c + pc, d + pd, bitmaps.shape[1]
+    words = jnp.take(bm, jnp.maximum(rowids_p, 0) >> 5,
+                     axis=1).transpose(1, 0, 2)       # (U, Qp, Cp)
+    qp, dp = nq + pq, d + pd
+    row_tile = pl.BlockSpec((1, 1, tc), lambda i, j: (i, 0, j))
+    score_tile = pl.BlockSpec((1, qp, tc), lambda i, j: (i, 0, j))
     out = pl.pallas_call(
         functools.partial(_leaf_scan_batched_kernel, metric=metric),
-        grid=(u,),
+        grid=(u, cp // tc),
         in_specs=[
-            pl.BlockSpec((qp, dp), lambda i: (0, 0)),         # query block
-            pl.BlockSpec((1, cp, dp), lambda i: (i, 0, 0)),   # leaf tile
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),          # rowids
-            pl.BlockSpec((1, dp), lambda i: (0, 0)),          # scale
-            pl.BlockSpec((1, dp), lambda i: (0, 0)),          # mean
-            pl.BlockSpec((1, cp), lambda i: (i, 0)),          # row norms
-            pl.BlockSpec((qp, w), lambda i: (0, 0)),          # bitmaps
+            pl.BlockSpec((qp, dp), lambda i, j: (0, 0)),         # query block
+            pl.BlockSpec((1, tc, dp), lambda i, j: (i, j, 0)),   # leaf rows
+            row_tile,                                            # rowids
+            pl.BlockSpec((1, dp), lambda i, j: (0, 0)),          # scale
+            pl.BlockSpec((1, dp), lambda i, j: (0, 0)),          # mean
+            row_tile,                                            # row norms
+            score_tile,                                          # words
         ],
-        out_specs=pl.BlockSpec((1, qp, cp), lambda i: (i, 0, 0)),
+        out_specs=score_tile,
         out_shape=jax.ShapeDtypeStruct((u, qp, cp), jnp.float32),
         interpret=interpret,
-    )(q, tiles_p, rowids_p, s, m, norms_p, bm)
+    )(q, tiles_p, rowids_p[:, None], s, m, norms_p[:, None], words)
     return out.transpose(1, 0, 2)[:nq, :, :c]

@@ -1,18 +1,18 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On the CPU container the kernels run in `interpret=True` mode (Pallas
-executes the kernel body with the same blocking); on TPU they compile to
-Mosaic.  `use_pallas=False` falls through to the jnp oracles — tests compare
-both paths.
+`_interpret()` is the one place that decides how a kernel runs: compiled
+by Mosaic when JAX's default backend is a TPU, and in Pallas interpret
+mode (the kernel body executed with the same blocking, on any backend)
+everywhere else — which is how the CPU test suite exercises them.
+`use_pallas=False` falls through to the jnp oracles — tests compare both
+paths.  tests/test_tpu_compile.py compiles the kernels for a described
+v5e chip, since interpret mode cannot see tiling or VMEM refusals.
 """
 from __future__ import annotations
 
 from functools import partial
 
 import jax
-
-from repro import compat
-
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -24,10 +24,10 @@ from repro.kernels.frontier_scan import (frontier_scan_excl_pallas,
                                          frontier_scan_pallas,
                                          frontier_scan_sq8_pallas)
 from repro.kernels.leaf_scan import leaf_scan_batched_pallas, leaf_scan_pallas
-from repro.kernels.topk import topk_pallas
 
 
 def _interpret() -> bool:
+    """True off the TPU: Pallas kernels then run in interpret mode."""
     return jax.default_backend() != "tpu"
 
 
@@ -138,18 +138,11 @@ def frontier_scan_excl_sq8(queries, qvecs, scale, mean, norms, ids, bitmaps,
                                           margin)
 
 
-@partial(jax.jit, static_argnames=("k", "use_pallas"))
-def topk_smallest(values, k: int, use_pallas: bool = True):
-    if use_pallas:
-        return topk_pallas(values, k, interpret=_interpret())
-    return ref.topk_partial_ref(values, k)
-
-
 def flash_attention_fused(q, k, v, causal: bool = True):
     """Pallas flash attention, shard_map-wrapped when a mesh is active:
     batch shards over (pod, data), kv heads over `model` (when divisible).
     Interpret mode on non-TPU backends."""
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     interp = _interpret()
     if mesh is None or mesh.empty:
         return flash_attention_pallas(q, k, v, causal=causal,
@@ -164,7 +157,7 @@ def flash_attention_fused(q, k, v, causal: bool = True):
     kvspec = "model" if ("model" in sizes
                          and k.shape[2] % sizes["model"] == 0) else None
     qs = P(bspec, None, kvspec, None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda q_, k_, v_: flash_attention_pallas(q_, k_, v_, causal=causal,
                                                   interpret=interp),
         mesh=mesh, in_specs=(qs, qs, qs), out_specs=qs, check_vma=False)

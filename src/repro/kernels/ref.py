@@ -1,14 +1,21 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose targets)."""
+"""Pure-jnp oracles for every Pallas kernel (the allclose targets).
+
+Their contractions run at `HIGHEST` precision: f32 on the TPU's MXU, as
+on the CPU (where the flag changes no bit).  At XLA's default precision a
+TPU rounds f32 matmul operands to bf16.
+"""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def distance_matrix_ref(queries: jax.Array, rows: jax.Array,
                         metric: str = "l2") -> jax.Array:
     """(Q, N) distances; lower = closer. queries (Q, d), rows (N, d) f32."""
-    ip = queries @ rows.T
+    ip = jnp.matmul(queries, rows.T, precision=HIGHEST)
     if metric == "ip":
         return -ip
     qn = jnp.sum(queries * queries, axis=1, keepdims=True)
@@ -37,11 +44,12 @@ def leaf_scan_ref(query: jax.Array, tiles: jax.Array, rowids: jax.Array,
     """
     x = tiles.astype(jnp.float32) * scale + mean          # (nl, C, d)
     if metric == "ip":
-        d = -jnp.einsum("lcd,d->lc", x, query)
+        d = -jnp.einsum("lcd,d->lc", x, query, precision=HIGHEST)
     else:
         qn = jnp.sum(query * query)
         xn = jnp.sum(x * x, axis=-1)
-        d = qn + xn - 2.0 * jnp.einsum("lcd,d->lc", x, query)
+        d = qn + xn - 2.0 * jnp.einsum("lcd,d->lc", x, query,
+                                       precision=HIGHEST)
     ok = probe_bitmap_ref(bitmap, rowids)
     return jnp.where(ok, d, jnp.inf)
 
@@ -66,7 +74,7 @@ def leaf_scan_batched_ref(queries: jax.Array, tiles: jax.Array,
     returns (Q, U, C) f32 scores with +inf where padded or filtered out.
     """
     x = tiles.astype(jnp.float32) * scale + mean          # (U, C, d)
-    ip = jnp.einsum("qd,ucd->quc", queries, x)
+    ip = jnp.einsum("qd,ucd->quc", queries, x, precision=HIGHEST)
     if metric == "ip":
         d = -ip
     else:
@@ -193,20 +201,3 @@ def frontier_scan_excl_sq8_ref(queries: jax.Array, qvecs: jax.Array,
     d, ok = frontier_scan_sq8_ref(queries, qvecs, scale, mean, norms, ids,
                                   bitmaps, metric)
     return d, ok, excl_keep_mask(d, excl, tau, ok, margin)
-
-
-def topk_partial_ref(values: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
-    """Global k smallest (values, indices) over a 1-D array.
-
-    Mirrors topk_pallas's sentinel contract: +inf entries (the universal
-    filtered/padded marker) and k > n overflow slots report index -1."""
-    n = values.shape[0]
-    kk = min(k, n)
-    neg, idx = jax.lax.top_k(-values, kk)
-    vals = -neg
-    idx = jnp.where(vals == jnp.inf, -1, idx)
-    if kk < k:
-        vals = jnp.concatenate(
-            [vals, jnp.full((k - kk,), jnp.inf, vals.dtype)])
-        idx = jnp.concatenate([idx, jnp.full((k - kk,), -1, idx.dtype)])
-    return vals, idx

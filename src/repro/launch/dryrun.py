@@ -23,8 +23,6 @@ import sys
 import time
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 
 from repro.configs import SHAPES, applicable_shapes, get_config
@@ -214,7 +212,7 @@ def lower_cell(arch_id: str, shape_name: str, multi_pod: bool = False,
     chips = mesh.devices.size
     t0 = time.time()
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         key_sds = jax.ShapeDtypeStruct((2,), jnp.uint32)
         pshape = jax.eval_shape(bundle.init, key_sds)
         pspec = param_specs(cfg, pshape, mesh)

@@ -18,8 +18,6 @@ import json
 import time
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -86,7 +84,7 @@ def main() -> None:
     executor = DistributedScannExecutor(sharded, use_pallas=args.pallas,
                                         heap_layout="leaf_ordered")
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn = executor.raw_search_fn(params)
         idx, store = sharded.index, sharded.store
         sargs = (idx.leaf_tiles, idx.leaf_rowids, idx.leaf_centroids,

@@ -18,8 +18,6 @@ import difflib
 
 import jax
 
-from repro import compat
-
 # Axis names the repo's shard_map programs bind (core/distributed.py,
 # launch/fvs_dryrun.py): misspelling one of these is the typo class the
 # validator catches — any OTHER novel name is legal, just unknown.
@@ -82,7 +80,9 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...],
     validate_mesh_request(
         shape, axes,
         num_devices=len(jax.devices()) if check_devices else None)
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh) -> tuple[str, ...]:

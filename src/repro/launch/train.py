@@ -14,8 +14,6 @@ import dataclasses
 import time
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 
@@ -75,7 +73,7 @@ def main() -> None:
     ctx = None
     if args.mesh:
         mesh = make_production_mesh(multi_pod=args.mesh == "multi")
-        ctx = compat.set_mesh(mesh)
+        ctx = jax.set_mesh(mesh)
         ctx.__enter__()
 
     trainer = Trainer(bundle, opt_cfg, tc, batch_fn)

@@ -14,8 +14,6 @@ import dataclasses
 from typing import Optional
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
@@ -29,7 +27,7 @@ def shard(x: jax.Array, *axes):
     """with_sharding_constraint that no-ops without an active mesh, drops
     axis names the mesh doesn't have, and drops axes that don't divide the
     dim (avoids GSPMD forced-remat on e.g. 8 kv heads over a 16-way axis)."""
-    m = compat.get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     if m is None or m.empty:
         return x
     sizes = dict(zip(m.axis_names, m.axis_sizes))
@@ -417,7 +415,7 @@ def moe_block(params: dict, x: jax.Array, cfg: ArchConfig,
 
 
 def _model_axis_size() -> int:
-    m = compat.get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     if m is None or m.empty or "model" not in m.axis_names:
         return 1
     return dict(zip(m.axis_names, m.axis_sizes))["model"]
@@ -432,7 +430,7 @@ def _ep_local_combine(oo, xt, gate, route, cap: int, n_g: int, d: int):
     axis.  Implemented as a manual shard_map over `model` (data/pod stay
     auto-sharded).
     """
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
 
     def local(oo_l, w_, se, rk, kp, tk):
         # oo_l: (G, E/shard, C, d) — this shard's experts only
@@ -456,7 +454,7 @@ def _ep_local_combine(oo, xt, gate, route, cap: int, n_g: int, d: int):
                                    axis=1)
     baxes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     g_spec = baxes if len(baxes) > 1 else (baxes[0] if baxes else None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(g_spec, "model", None, None), P(g_spec), P(g_spec),
                   P(g_spec), P(g_spec), P(g_spec)),

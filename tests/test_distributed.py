@@ -2,6 +2,7 @@
 subprocess with 8 forced host devices (XLA locks the device count at
 first init, so the main test process stays single-device)."""
 import json
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -13,6 +14,8 @@ import pytest
 from repro.configs import ARCH_IDS, get_config
 from repro.launch.sharding import fit_spec, param_specs
 from jax.sharding import PartitionSpec as P
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 class FakeMesh:
@@ -123,7 +126,7 @@ _SUBPROCESS_SRC = textwrap.dedent("""
 @pytest.mark.slow
 def test_distributed_search_8dev():
     proc = subprocess.run([sys.executable, "-c", _SUBPROCESS_SRC],
-                          capture_output=True, text=True, cwd="/root/repo",
+                          capture_output=True, text=True, cwd=REPO,
                           timeout=600)
     assert proc.returncode == 0, proc.stderr[-2000:]
     rec = json.loads(proc.stdout.splitlines()[-1])

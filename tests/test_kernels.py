@@ -63,23 +63,6 @@ def test_leaf_scan_sweep(nl, c, d, metric, density, seed):
                                atol=2e-3, rtol=1e-3)
 
 
-@settings(max_examples=12, deadline=None)
-@given(n=st.integers(1, 5000), k=st.integers(1, 64),
-       seed=st.integers(0, 99))
-def test_topk_sweep(n, k, seed):
-    k = min(k, n)
-    rng = np.random.RandomState(seed)
-    v = jnp.asarray(rng.randn(n).astype(np.float32))
-    av, ai = ops.topk_smallest(v, k, use_pallas=True)
-    bv, bi = ref.topk_partial_ref(v, k)
-    np.testing.assert_allclose(np.sort(np.asarray(av)),
-                               np.sort(np.asarray(bv)), atol=1e-6)
-    # indices must point at the right values
-    va = np.asarray(v)[np.asarray(ai)]
-    np.testing.assert_allclose(np.sort(va), np.sort(np.asarray(bv)),
-                               atol=1e-6)
-
-
 def test_leaf_scan_all_filtered():
     """Fully-failing filter -> all +inf (empty result is well-defined)."""
     rng = np.random.RandomState(0)

@@ -1,6 +1,6 @@
 """Leaf-scan kernel coverage (no hypothesis): Pallas-vs-ref equivalence for
 the single and batched variants, padding edges, all-filtered bitmaps,
-top-k with k > n, and batched-pipeline-vs-vmapped ScaNN equivalence."""
+top-k sentinels, and batched-pipeline-vs-vmapped ScaNN equivalence."""
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -8,7 +8,7 @@ import pytest
 
 from repro.core import (SearchParams, WorkloadSpec, generate_bitmaps,
                         scann_search_batch, scann_search_batch_vmapped)
-from repro.core.types import pack_bool_bitmap
+from repro.core.types import merge_topk, pack_bool_bitmap
 from repro.kernels import ops, ref
 
 
@@ -109,27 +109,29 @@ def test_leaf_scan_batched_mixed_filters():
     assert not np.isfinite(out[1]).any()
 
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_topk_k_greater_than_n(use_pallas):
-    """k > n must yield the n real entries plus (+inf, -1) padding, on
-    both the Pallas kernel and the jnp oracle."""
-    v = jnp.asarray(np.array([3.0, 1.0, 2.0], np.float32))
-    vals, idx = ops.topk_smallest(v, 8, use_pallas=use_pallas)
-    vals, idx = np.asarray(vals), np.asarray(idx)
+def test_topk_k_greater_than_n():
+    """k above the number of finite candidates yields the real entries
+    plus (+inf, -1) padding (the top-k every executor's result uses)."""
+    a = jnp.asarray([[3.0, 1.0, 2.0]], jnp.float32)
+    b = jnp.full((1, 5), jnp.inf, jnp.float32)
+    vals, idx = merge_topk(a, jnp.asarray([[30, 10, 20]], jnp.int32), b,
+                           jnp.full((1, 5), -1, jnp.int32), 8)
+    vals, idx = np.asarray(vals)[0], np.asarray(idx)[0]
     np.testing.assert_allclose(vals[:3], [1.0, 2.0, 3.0])
-    assert (idx[:3] == [1, 2, 0]).all()
+    assert (idx[:3] == [10, 20, 30]).all()
     assert np.isinf(vals[3:]).all()
     assert (idx[3:] == -1).all()
 
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_topk_inf_sentinels(use_pallas):
-    """+inf (the universal filtered marker) reports index -1 on both
-    backends; -inf is a legitimate smallest value and keeps its index."""
-    v = jnp.asarray(np.array([np.inf, 1.0, np.inf, -np.inf], np.float32))
-    vals, idx = ops.topk_smallest(v, 4, use_pallas=use_pallas)
-    vals, idx = np.asarray(vals), np.asarray(idx)
-    assert vals[0] == -np.inf and idx[0] == 3
+def test_topk_inf_sentinels():
+    """+inf (the universal filtered marker) reports index -1 even where
+    the input carried a real id; finite values keep theirs."""
+    a = jnp.asarray([[np.inf, 1.0]], jnp.float32)
+    b = jnp.asarray([[np.inf, -2.0]], jnp.float32)
+    vals, idx = merge_topk(a, jnp.asarray([[0, 1]], jnp.int32), b,
+                           jnp.asarray([[2, 3]], jnp.int32), 4)
+    vals, idx = np.asarray(vals)[0], np.asarray(idx)[0]
+    assert vals[0] == -2.0 and idx[0] == 3
     assert vals[1] == 1.0 and idx[1] == 1
     assert np.isposinf(vals[2:]).all()
     assert (idx[2:] == -1).all()

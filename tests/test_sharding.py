@@ -8,6 +8,7 @@ volume.  Multi-device shard_map execution runs in a subprocess with 8
 forced host devices (XLA locks the device count at first init)."""
 import dataclasses
 import json
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -29,6 +30,8 @@ from repro.data import DatasetSpec, make_dataset, make_dataset_streamed
 from repro.data.datasets import _stream_block, _stream_centers
 from repro.launch.mesh import make_mesh, validate_mesh_request
 from repro.storage import make_storage_engine
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 STRATEGIES = ("unfiltered", "sweeping", "acorn", "navix", "iterative_scan")
 
@@ -314,7 +317,7 @@ def test_shard_map_matches_vmap_8dev():
     """The same shard body under real shard_map devices reproduces the
     single-process vmap executor bit-exactly."""
     proc = subprocess.run([sys.executable, "-c", _SUBPROCESS_SRC],
-                          capture_output=True, text=True, cwd="/root/repo",
+                          capture_output=True, text=True, cwd=REPO,
                           timeout=600)
     assert proc.returncode == 0, proc.stderr[-2000:]
     rec = json.loads(proc.stdout.splitlines()[-1])
