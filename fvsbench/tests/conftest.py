@@ -1,0 +1,13 @@
+"""The benchmark's own tests, outside the program's suite:
+
+    JAX_PLATFORMS=cpu python -m pytest -q fvsbench/tests
+
+from the root of a checkout."""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+for p in (ROOT, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
