@@ -38,6 +38,7 @@ from typing import Mapping, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core.types import (AnytimeInfo, SearchParams, SearchStats,
                               heap_pages_per_vector,
                               quant_heap_pages_per_vector)
@@ -683,33 +684,34 @@ def evaluate_anytime(stats: Optional[SearchStats], params: SearchParams,
     truncation the counters cannot show (e.g. a plan-level leaf clamp or
     a bruteforce partial-scan row cap).
     """
-    ids = np.asarray(ids)
-    completion = np.mean(ids >= 0, axis=-1, dtype=np.float32)
-    completion = np.atleast_1d(completion)
-    q = completion.shape[0]
-    budget = np.zeros(q, bool)
-    truncated = np.zeros(q, bool)
-    if stats is not None:
-        hops = np.atleast_1d(np.asarray(stats.hops, np.int64))
-        pages = np.atleast_1d(
-            np.asarray(stats.page_accesses_index, np.int64)
-            + np.asarray(stats.page_accesses_heap, np.int64))
-        if params.page_budget > 0:
-            budget |= pages >= params.page_budget
-        if params.hop_budget > 0:
-            budget |= hops >= params.hop_budget
-        if params.deadline_cycles > 0:
-            budget |= linear_cycles(stats, dim, constants) \
-                >= params.deadline_cycles
-        if hop_cap is not None:
-            truncated |= hops >= hop_cap
-    if extra_budget is not None:
-        budget |= np.atleast_1d(np.asarray(extra_budget, bool))
-    truncated |= budget
-    if extra_truncated is not None:
-        truncated |= np.atleast_1d(np.asarray(extra_truncated, bool))
-    return AnytimeInfo(truncated=truncated, budget_exhausted=budget,
-                       completion=completion)
+    with obs.span("executor.anytime"):
+        ids = np.asarray(ids)
+        completion = np.mean(ids >= 0, axis=-1, dtype=np.float32)
+        completion = np.atleast_1d(completion)
+        q = completion.shape[0]
+        budget = np.zeros(q, bool)
+        truncated = np.zeros(q, bool)
+        if stats is not None:
+            hops = np.atleast_1d(np.asarray(stats.hops, np.int64))
+            pages = np.atleast_1d(
+                np.asarray(stats.page_accesses_index, np.int64)
+                + np.asarray(stats.page_accesses_heap, np.int64))
+            if params.page_budget > 0:
+                budget |= pages >= params.page_budget
+            if params.hop_budget > 0:
+                budget |= hops >= params.hop_budget
+            if params.deadline_cycles > 0:
+                budget |= linear_cycles(stats, dim, constants) \
+                    >= params.deadline_cycles
+            if hop_cap is not None:
+                truncated |= hops >= hop_cap
+        if extra_budget is not None:
+            budget |= np.atleast_1d(np.asarray(extra_budget, bool))
+        truncated |= budget
+        if extra_truncated is not None:
+            truncated |= np.atleast_1d(np.asarray(extra_truncated, bool))
+        return AnytimeInfo(truncated=truncated, budget_exhausted=budget,
+                           completion=completion)
 
 
 def queueing_delay_cycles(offered_per_cycle: float, service_cycles: float,

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import costmodel
 from repro.core.bruteforce import filtered_knn, filtered_knn_partial
 from repro.core.exclusion import ExclusionIndex, match_families, select_radii
@@ -95,7 +96,10 @@ class BaseExecutor:
     name: str = "base"
 
     def search(self, queries, bitmaps, params: SearchParams) -> SearchResult:
-        return self.execute(self.plan(queries, bitmaps, params))
+        with obs.span("executor.plan"):
+            plan = self.plan(queries, bitmaps, params)
+        with obs.span("executor.execute"):
+            return self.execute(plan)
 
     def plan(self, queries, bitmaps, params: SearchParams) -> SearchPlan:
         raise NotImplementedError

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.types import VectorStore
 
 
@@ -548,8 +549,30 @@ def build_graph_blocked(store: VectorStore, m: int = 16,
     O(n²) exact kNN for `_knn_routed` and the python-loop reverse/repair
     passes for their vectorized twins.  Same topology class, not
     bit-identical to `build_graph`.
+
+    Spans (`repro.obs`): `hnsw.build` holds `hnsw.fetch` (vectors to the
+    host), per level `hnsw.knn`, `hnsw.prune` and `hnsw.link` (args
+    `level`, `members`), and `hnsw.upload` (the graph onto the device,
+    ended once it is there).
     """
-    vectors = np.asarray(store.vectors)
+    with obs.span("hnsw.build"):
+        with obs.span("hnsw.fetch"):
+            vectors = np.asarray(store.vectors)
+        nbrs, levels, entry = _link_levels_blocked(
+            vectors, store.metric, m, ef_construction, seed, max_level,
+            exact_threshold, route_expand)
+        with obs.span("hnsw.upload"):
+            return jax.block_until_ready(HNSWGraph(
+                neighbors=jnp.asarray(nbrs, jnp.int32),
+                node_level=jnp.asarray(levels, jnp.int32),
+                entry_point=jnp.asarray(entry, jnp.int32), m=m))
+
+
+def _link_levels_blocked(vectors: np.ndarray, metric: str, m: int,
+                         ef_construction: int, seed: int,
+                         max_level: int | None, exact_threshold: int,
+                         route_expand: int):
+    """`build_graph_blocked` on the host: (neighbors, levels, entry)."""
     n = vectors.shape[0]
     rng = np.random.RandomState(seed)
     ml = 1.0 / np.log(max(m, 2))
@@ -567,45 +590,47 @@ def build_graph_blocked(store: VectorStore, m: int = 16,
         members = np.where(levels >= lvl)[0]
         if len(members) <= 1:
             continue
-        mv = vectors[members]
-        m_l = mmax0 if lvl == 0 else m
-        kc = min(max(ef_construction, m_l + 8), len(members) - 1)
-        if len(members) <= exact_threshold:
-            cand_local, cand_d = _knn_among(mv, store.metric, kc)
-        else:
-            cand_local, cand_d = _knn_routed(mv, store.metric, kc, rng,
-                                             route_expand=route_expand)
         n_m = len(members)
-        n_rand = min(8, n_m - 1)
-        if n_rand > 0:
-            rnd = rng.randint(0, n_m, size=(n_m, n_rand)).astype(np.int64)
-            rnd = np.where(rnd == np.arange(n_m)[:, None],
-                           (rnd + 1) % n_m, rnd)
-            rd = _rows_dist(mv, rnd, store.metric)
-            cand_local = np.concatenate([cand_local, rnd], 1)
-            cand_d = np.concatenate([cand_d, rd], 1)
-            order = np.argsort(cand_d, axis=1, kind="stable")
-            cand_local = np.take_along_axis(cand_local, order, 1)
-            cand_d = np.take_along_axis(cand_d, order, 1)
-        pruned_local = _diversity_prune(mv, cand_local, cand_d, m_l,
-                                        store.metric)
-        valid = pruned_local >= 0
-        pruned = np.where(valid, members[np.clip(pruned_local, 0, None)], -1)
-        nbrs[lvl, members, :m_l] = pruned[:, :m_l]
-        if len(members) <= exact_threshold:
-            _augment_reverse(nbrs[lvl], members, pruned, m_l)
-        else:
-            _augment_reverse_blocked(nbrs[lvl], members, pruned, m_l)
-        if lvl == 0:
-            if n <= exact_threshold:
-                _repair_connectivity(nbrs[0], vectors, store.metric)
+        at = {"level": lvl, "members": n_m}
+        m_l = mmax0 if lvl == 0 else m
+        with obs.span("hnsw.knn", **at):
+            mv = vectors[members]
+            kc = min(max(ef_construction, m_l + 8), n_m - 1)
+            if n_m <= exact_threshold:
+                cand_local, cand_d = _knn_among(mv, metric, kc)
             else:
-                _repair_connectivity_blocked(nbrs[0], vectors,
-                                             store.metric, rng)
-
-    return HNSWGraph(neighbors=jnp.asarray(nbrs, jnp.int32),
-                     node_level=jnp.asarray(levels, jnp.int32),
-                     entry_point=jnp.asarray(entry, jnp.int32), m=m)
+                cand_local, cand_d = _knn_routed(mv, metric, kc, rng,
+                                                 route_expand=route_expand)
+            n_rand = min(8, n_m - 1)
+            if n_rand > 0:
+                rnd = rng.randint(0, n_m, size=(n_m, n_rand)).astype(np.int64)
+                rnd = np.where(rnd == np.arange(n_m)[:, None],
+                               (rnd + 1) % n_m, rnd)
+                rd = _rows_dist(mv, rnd, metric)
+                cand_local = np.concatenate([cand_local, rnd], 1)
+                cand_d = np.concatenate([cand_d, rd], 1)
+                order = np.argsort(cand_d, axis=1, kind="stable")
+                cand_local = np.take_along_axis(cand_local, order, 1)
+                cand_d = np.take_along_axis(cand_d, order, 1)
+        with obs.span("hnsw.prune", **at):
+            pruned_local = _diversity_prune(mv, cand_local, cand_d, m_l,
+                                            metric)
+        with obs.span("hnsw.link", **at):
+            valid = pruned_local >= 0
+            pruned = np.where(valid, members[np.clip(pruned_local, 0, None)],
+                              -1)
+            nbrs[lvl, members, :m_l] = pruned[:, :m_l]
+            if n_m <= exact_threshold:
+                _augment_reverse(nbrs[lvl], members, pruned, m_l)
+            else:
+                _augment_reverse_blocked(nbrs[lvl], members, pruned, m_l)
+            if lvl == 0:
+                if n <= exact_threshold:
+                    _repair_connectivity(nbrs[0], vectors, metric)
+                else:
+                    _repair_connectivity_blocked(nbrs[0], vectors, metric,
+                                                 rng)
+    return nbrs, levels, entry
 
 
 # ---------------------------------------------------------------------------
