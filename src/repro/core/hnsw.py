@@ -1,12 +1,14 @@
 """HNSW-family navigable graph: construction + container.
 
 Construction here is the *deterministic, vectorizable* variant described in
-DESIGN.md §8(2): geometric level assignment exactly as HNSW, per-level exact
-kNN candidate generation, and the standard HNSW select-neighbors *diversity
+DESIGN.md §8(2): geometric level assignment exactly as HNSW, per-level kNN
+candidate generation, and the standard HNSW select-neighbors *diversity
 heuristic* for pruning, plus reverse-edge augmentation.  This produces the
 same navigable-small-world topology class the paper's pgvector index has
-(M connections per node per layer, 2M at the base layer), while being
-buildable in seconds on CPU.  An incremental reference builder
+(M connections per node per layer, 2M at the base layer).  `build_graph`
+runs on the host and suits small stores; `build_graph_blocked`, the
+builder for large ones, computes its candidates on the device and prunes
+and links on the host.  An incremental reference builder
 (`build_incremental`) with classic insert semantics is kept for small-N
 validation tests.
 
@@ -17,12 +19,14 @@ of `neighbors[l]` is one "index page access".
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro import obs
 from repro.core.types import VectorStore
@@ -412,7 +416,9 @@ def build_incremental(store: VectorStore, m: int = 16,
 # `route_expand` nearest of ~2√n sampled centroids and take exact kNN
 # within the routed buckets (expected candidate work ≈ expand·n²/C).
 # Small levels (< exact_threshold members) still use the exact kNN, so
-# upper navigation layers are identical in kind to build_graph's.
+# upper navigation layers are identical in kind to build_graph's.  The
+# builder computes both on the device (`_knn_device`); `_knn_among` and
+# `_knn_routed` are their host twins.
 # ---------------------------------------------------------------------------
 
 def _knn_routed(mv: np.ndarray, metric: str, kc: int,
@@ -422,23 +428,16 @@ def _knn_routed(mv: np.ndarray, metric: str, kc: int,
     """Approximate kNN among rows via sampled-centroid bucket routing."""
     n = mv.shape[0]
     kc = min(kc, n - 1)
-    C = num_centroids or int(np.clip(2 * np.sqrt(n), 64, 4096))
-    C = min(C, n)
-    expand = min(route_expand, C)
-    cents = mv[rng.choice(n, C, replace=False)]
+    cent_rows, expand = _route_centroids(n, rng, route_expand, num_centroids)
+    C = len(cent_rows)
+    cents = mv[cent_rows]
     routes = np.empty((n, expand), np.int64)
     for s in range(0, n, 8192):
         e = min(s + 8192, n)
         d = _pairwise_dists(mv[s:e], cents, metric)
         routes[s:e] = np.argpartition(d, expand - 1, axis=1)[:, :expand]
-    primary = routes[:, 0]
-    order = np.argsort(primary, kind="stable")
-    bounds = np.searchsorted(primary[order], np.arange(C + 1))
-    # rows querying bucket c = rows routing to c through ANY slot
-    q_order = np.argsort(routes.reshape(-1), kind="stable")
+    order, bounds, q_order, q_bounds = _bucket_order(routes, C)
     q_rows = q_order // expand
-    q_bounds = np.searchsorted(routes.reshape(-1)[q_order],
-                               np.arange(C + 1))
     ids = np.full((n, kc), -1, np.int64)
     dst = np.full((n, kc), np.inf, np.float32)
     for c in range(C):
@@ -460,19 +459,34 @@ def _knn_routed(mv: np.ndarray, metric: str, kc: int,
         o = np.argsort(sd, axis=1, kind="stable")
         dst[qr] = np.take_along_axis(sd, o, axis=1)
         ids[qr] = np.take_along_axis(si, o, axis=1)
-    # a row can reach the same neighbor through several buckets: mask the
-    # sorted-adjacent duplicates so the pruner never keeps a repeat
-    dup = np.zeros_like(ids, bool)
-    srt = np.sort(ids, axis=1)
-    inv = np.argsort(ids, axis=1, kind="stable")
-    dup_sorted = np.concatenate(
-        [np.zeros((n, 1), bool), srt[:, 1:] == srt[:, :-1]], axis=1)
-    np.put_along_axis(dup, inv, dup_sorted, axis=1)
-    dst[dup] = np.inf
-    ids[dup] = -1
-    o = np.argsort(dst, axis=1, kind="stable")
-    return (np.take_along_axis(ids, o, axis=1),
-            np.take_along_axis(dst, o, axis=1))
+    # a row's routes are distinct buckets, and buckets partition the rows,
+    # so no candidate repeats
+    return ids, dst
+
+
+def _route_centroids(n: int, rng: np.random.RandomState, route_expand: int,
+                     num_centroids: int | None) -> tuple[np.ndarray, int]:
+    """The rows drawn as centroids (~2√n of them), and how many of them
+    each row routes to."""
+    C = num_centroids or int(np.clip(2 * np.sqrt(n), 64, 4096))
+    C = min(C, n)
+    return rng.choice(n, C, replace=False), min(route_expand, C)
+
+
+def _bucket_order(routes: np.ndarray, C: int):
+    """Group rows by bucket (their primary route) and (row, slot) pairs by
+    the bucket they query.  Returns `order` (rows by bucket, stable),
+    `bounds` (bucket c is `order[bounds[c]:bounds[c + 1]]`), `q_order`
+    (flat pair index i * expand + slot, by queried bucket, stable) and
+    `q_bounds`."""
+    primary = routes[:, 0]
+    order = np.argsort(primary, kind="stable")
+    bounds = np.searchsorted(primary[order], np.arange(C + 1))
+    # rows querying bucket c = rows routing to c through ANY slot
+    flat = routes.reshape(-1)
+    q_order = np.argsort(flat, kind="stable")
+    q_bounds = np.searchsorted(flat[q_order], np.arange(C + 1))
+    return order, bounds, q_order, q_bounds
 
 
 def _augment_reverse_blocked(level_nbrs: np.ndarray, members: np.ndarray,
@@ -537,6 +551,212 @@ def _repair_connectivity_blocked(level_nbrs: np.ndarray,
                 row[free[0] if len(free) else len(row) - 1] = v
 
 
+# ---------------------------------------------------------------------------
+# Candidate generation on the device: the `hnsw.knn` stage of
+# `build_graph_blocked`.  The same candidates as `_knn_among` (levels of up
+# to exact_threshold members) and `_knn_routed` (larger levels), plus the
+# random long-range extras and the sort of both, from two jitted programs:
+# f32 distances at HIGHEST precision, exact `lax.top_k`.  The centroids,
+# the extras and the grouping of (row, route) pairs by bucket are drawn on
+# the host, so the build consumes the same rng stream.  An exact level is
+# one bucket holding every member, queried once per row.  Every shape is
+# padded (rows to a multiple of _ROW_CLASS, centroids to a power of two)
+# and the loops run for a traced count of tiles, so the programs depend on
+# d, metric, kc and the store's row class, never on a level's member
+# count or the seed.
+# ---------------------------------------------------------------------------
+
+_ROW_CLASS = 4096   # store rows are padded to a multiple of this
+_TQ = 512           # (row, route) pairs per query tile
+_TC = 512           # bucket members per column tile
+_RB = 512           # rows per block when routing and merging
+_N_EXTRA = 8        # random long-range candidates per row
+
+
+def _dists_dev(x: jax.Array, y: jax.Array, metric: str) -> jax.Array:
+    """`_pairwise_dists` on the device, f32 at HIGHEST precision."""
+    dot = functools.partial(jnp.dot, precision=lax.Precision.HIGHEST)
+    if metric == "ip":
+        return -dot(x, y.T)
+    if metric == "cos":
+        xn = x / (jnp.linalg.norm(x, axis=1, keepdims=True) + 1e-12)
+        yn = y / (jnp.linalg.norm(y, axis=1, keepdims=True) + 1e-12)
+        return 1.0 - dot(xn, yn.T)
+    d = (x * x).sum(1)[:, None] + (y * y).sum(1)[None, :] - 2.0 * dot(x, y.T)
+    return jnp.maximum(d, 0.0)
+
+
+def _rows_dist_dev(x: jax.Array, y: jax.Array, metric: str) -> jax.Array:
+    """`_rows_dist` on the device: x (b, d) against y (b, k, d) -> (b, k)."""
+    x = x[:, None, :]
+    if metric == "ip":
+        return -(x * y).sum(2)
+    if metric == "cos":
+        xn = x / (jnp.linalg.norm(x, axis=2, keepdims=True) + 1e-12)
+        yn = y / (jnp.linalg.norm(y, axis=2, keepdims=True) + 1e-12)
+        return 1.0 - (xn * yn).sum(2)
+    diff = y - x
+    return (diff * diff).sum(2)
+
+
+def _smallest(d: jax.Array, ids: jax.Array, k: int):
+    """The k smallest distances of each row, ascending, and their ids."""
+    neg, pos = lax.top_k(-d, k)
+    return -neg, jnp.take_along_axis(ids, pos, axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("metric", "expand"))
+def _routes_dev(vecs, members, cents, n_blocks, *, metric, expand):
+    """Each member's `expand` nearest centroids, nearest first: (P, expand)
+    indices into `cents`.  vecs (P, d) store rows; members (P,) the
+    level's rows; cents (CP,) the centroids' rows; both -1 padded."""
+    xc = vecs[jnp.maximum(cents, 0)]
+
+    def block(b, out):
+        rows = lax.dynamic_slice(members, (b * _RB,), (_RB,))
+        d = _dists_dev(vecs[jnp.maximum(rows, 0)], xc, metric)
+        _, near = lax.top_k(-jnp.where(cents < 0, jnp.inf, d), expand)
+        return lax.dynamic_update_slice(out, near, (b * _RB, 0))
+
+    return lax.fori_loop(0, n_blocks, block,
+                         jnp.zeros((members.shape[0], expand), jnp.int32))
+
+
+@functools.partial(jax.jit, static_argnames=("metric", "kc"))
+def _knn_dev(vecs, members, cols, pairs, tiles, n_qtiles, pair_pos, extra,
+             n_blocks, *, metric, kc):
+    """The candidates of a level's rows: (P, kc + _N_EXTRA) distances and
+    local ids, ascending (stable), inf / -1 padded.
+
+    vecs (P, d) store rows; members (P,) the level's rows (local id i is
+    row members[i]).  cols (2, P + _TC): the members by bucket, as (local
+    id, bucket).  pairs (2, E * P): the (row, route) pairs by the bucket
+    they query, as (local id, bucket).  tiles (2, E * P / _TQ): for each
+    query tile of pairs, its first column and its count of column tiles.
+    pair_pos (P, E): where each row's pairs sit in `pairs`.  extra (P,
+    _N_EXTRA): random candidates.  Every array is -1 padded; the loops run
+    `n_qtiles` query tiles and `n_blocks` row blocks.
+    """
+    def rows_of(ids):
+        return vecs[jnp.maximum(members[jnp.maximum(ids, 0)], 0)]
+
+    def empty(n, k):
+        return (jnp.full((n, k), jnp.inf, jnp.float32),
+                jnp.full((n, k), -1, jnp.int32))
+
+    def q_tile(t, res):
+        qid = lax.dynamic_slice(pairs[0], (t * _TQ,), (_TQ,))
+        qkey = lax.dynamic_slice(pairs[1], (t * _TQ,), (_TQ,))
+        xq = rows_of(qid)
+
+        def c_tile(j, run):
+            s = tiles[0, t] + j * _TC
+            cid = lax.dynamic_slice(cols[0], (s,), (_TC,))
+            ckey = lax.dynamic_slice(cols[1], (s,), (_TC,))
+            drop = ((ckey[None, :] != qkey[:, None]) | (cid < 0)[None, :]
+                    | (cid[None, :] == qid[:, None]))
+            d = jnp.where(drop, jnp.inf, _dists_dev(xq, rows_of(cid), metric))
+            ids = jnp.where(drop, -1, cid[None, :])
+            return _smallest(jnp.concatenate([run[0], d], 1),
+                             jnp.concatenate([run[1], ids], 1), kc)
+
+        run = lax.fori_loop(0, tiles[1, t], c_tile, empty(_TQ, kc))
+        return tuple(lax.dynamic_update_slice(r, x, (t * _TQ, 0))
+                     for r, x in zip(res, run))
+
+    res = lax.fori_loop(0, n_qtiles, q_tile, empty(pairs.shape[1], kc))
+    expand = pair_pos.shape[1]
+
+    def block(b, out):
+        r0 = b * _RB
+        pos = lax.dynamic_slice(pair_pos, (r0, 0), (_RB, expand))
+        ok = (pos >= 0)[:, :, None]
+        pd = jnp.where(ok, res[0][jnp.maximum(pos, 0)], jnp.inf)
+        pi = jnp.where(ok, res[1][jnp.maximum(pos, 0)], -1)
+        kd, ki = _smallest(pd.reshape(_RB, -1), pi.reshape(_RB, -1), kc)
+        ex = lax.dynamic_slice(extra, (r0, 0), (_RB, _N_EXTRA))
+        ed = jnp.where(ex < 0, jnp.inf, _rows_dist_dev(
+            rows_of(r0 + jnp.arange(_RB)), rows_of(ex), metric))
+        cd = jnp.concatenate([kd, ed], 1)
+        ci = jnp.concatenate([ki, ex], 1)
+        o = jnp.argsort(cd, axis=1, stable=True)
+        return tuple(lax.dynamic_update_slice(a, jnp.take_along_axis(x, o, 1),
+                                              (r0, 0))
+                     for a, x in zip(out, (cd, ci)))
+
+    return lax.fori_loop(0, n_blocks, block,
+                         empty(members.shape[0], kc + _N_EXTRA))
+
+
+def _upload_rows(vectors: np.ndarray) -> jax.Array:
+    """The store's rows on the device, zero-padded to a multiple of
+    _ROW_CLASS: the one array every level's candidates are computed from."""
+    n, d = vectors.shape
+    pad = np.zeros((-(-n // _ROW_CLASS) * _ROW_CLASS, d), np.float32)
+    pad[:n] = vectors
+    return jax.device_put(pad)
+
+
+def _knn_device(vecs: jax.Array, members: np.ndarray, metric: str, kc: int,
+                rng: np.random.RandomState, routed: bool, route_expand: int
+                ) -> tuple[np.ndarray, np.ndarray, dict]:
+    """One level's candidates: `_knn_routed` (if `routed`, drawing its
+    centroids from `rng`) or `_knn_among`, then the random extras, sorted.
+
+    Returns local ids (n, min(kc, n - 1) + min(8, n - 1)) -1 padded, their
+    f32 distances (inf padded), and the counters `tiles` (query tile x
+    column tile steps run) and `pad_share` (share of the distances those
+    steps computed that were masked: padding, other buckets, self).
+    """
+    P = vecs.shape[0]
+    n = len(members)
+    mem = np.full(P, -1, np.int32)
+    mem[:n] = members
+    if routed:
+        cent_rows, expand = _route_centroids(n, rng, route_expand, None)
+        C = len(cent_rows)
+        cents = np.full(max(64, 1 << (C - 1).bit_length()), -1, np.int32)
+        cents[:C] = members[cent_rows]
+        routes = np.asarray(_routes_dev(vecs, mem, cents, -(-n // _RB),
+                                        metric=metric, expand=expand))[:n]
+    else:                        # one bucket holding every member
+        C, expand = 1, 1
+        routes = np.zeros((n, 1), np.int32)
+    order, bounds, q_order, q_bounds = _bucket_order(routes, C)
+    n_pairs = n * expand
+    cols = np.full((2, P + _TC), -1, np.int32)
+    cols[0, :n], cols[1, :n] = order, routes[order, 0]
+    pairs = np.full((2, expand * P), -1, np.int32)
+    pairs[0, :n_pairs] = q_order // expand
+    pairs[1, :n_pairs] = routes.reshape(-1)[q_order]
+    pos = np.empty(n_pairs, np.int32)
+    pos[q_order] = np.arange(n_pairs)
+    pair_pos = np.full((P, expand), -1, np.int32)
+    pair_pos[:n] = pos.reshape(n, expand)
+    # a query tile's pairs query a run of buckets: scan their members
+    n_qt = -(-n_pairs // _TQ)
+    ends = np.minimum(np.arange(1, n_qt + 1) * _TQ, n_pairs)
+    first = bounds[pairs[1, np.arange(n_qt) * _TQ]]
+    last = bounds[pairs[1, ends - 1] + 1]
+    tiles = np.zeros((2, expand * P // _TQ), np.int32)
+    tiles[0, :n_qt] = first
+    tiles[1, :n_qt] = -(-(last - first) // _TC)
+    n_rand = min(_N_EXTRA, n - 1)
+    rnd = rng.randint(0, n, size=(n, n_rand)).astype(np.int64)
+    rnd = np.where(rnd == np.arange(n)[:, None], (rnd + 1) % n, rnd)
+    extra = np.full((P, _N_EXTRA), -1, np.int32)
+    extra[:n, :n_rand] = rnd
+    d, ids = _knn_dev(vecs, mem, cols, pairs, tiles, n_qt, pair_pos, extra,
+                      -(-n // _RB), metric=metric, kc=kc)
+    w = min(kc, n - 1) + n_rand
+    cand_d = np.asarray(d)[:n, :w]
+    cand = np.asarray(ids)[:n, :w].astype(np.int64)
+    steps = int(tiles[1].sum())
+    useful = int((np.diff(q_bounds) * np.diff(bounds)).sum()) - n
+    return cand, cand_d, {"tiles": steps,
+                          "pad_share": 1.0 - useful / (steps * _TQ * _TC)}
+
+
 def build_graph_blocked(store: VectorStore, m: int = 16,
                         ef_construction: int = 32, seed: int = 0,
                         max_level: int | None = None,
@@ -544,16 +764,23 @@ def build_graph_blocked(store: VectorStore, m: int = 16,
                         route_expand: int = 3) -> HNSWGraph:
     """`build_graph` recipe with cluster-routed candidates on big levels.
 
-    Levels with <= `exact_threshold` members build exactly like
-    `build_graph`; larger levels (at 1M rows: levels 0 and 1) swap the
-    O(n²) exact kNN for `_knn_routed` and the python-loop reverse/repair
+    Levels with <= `exact_threshold` members take the exact kNN of
+    `_knn_among`; larger levels (at 1M rows: levels 0 and 1) take the
+    candidates of `_knn_routed` and swap the python-loop reverse/repair
     passes for their vectorized twins.  Same topology class, not
     bit-identical to `build_graph`.
+
+    Where each stage runs: level assignment, centroid and random-extra
+    draws on the host; the candidates (routes, kNN, extras and their
+    sort) on the device (`_knn_device`); pruning, linking and repair on
+    the host.
 
     Spans (`repro.obs`): `hnsw.build` holds `hnsw.fetch` (vectors to the
     host), per level `hnsw.knn`, `hnsw.prune` and `hnsw.link` (args
     `level`, `members`), and `hnsw.upload` (the graph onto the device,
-    ended once it is there).
+    ended once it is there).  `hnsw.knn` adds `on_device`, `tiles` and
+    `pad_share` (`_knn_device`'s counters) and ends once the candidates
+    are on the host.
     """
     with obs.span("hnsw.build"):
         with obs.span("hnsw.fetch"):
@@ -585,6 +812,7 @@ def _link_levels_blocked(vectors: np.ndarray, metric: str, m: int,
     entry = int(np.argmax(levels))
     mmax0 = 2 * m
     nbrs = np.full((top + 1, n, mmax0), -1, np.int64)
+    vecs = None              # the rows on the device, uploaded once
 
     for lvl in range(top + 1):
         members = np.where(levels >= lvl)[0]
@@ -593,25 +821,14 @@ def _link_levels_blocked(vectors: np.ndarray, metric: str, m: int,
         n_m = len(members)
         at = {"level": lvl, "members": n_m}
         m_l = mmax0 if lvl == 0 else m
-        with obs.span("hnsw.knn", **at):
+        with obs.span("hnsw.knn", **at) as knn:
             mv = vectors[members]
-            kc = min(max(ef_construction, m_l + 8), n_m - 1)
-            if n_m <= exact_threshold:
-                cand_local, cand_d = _knn_among(mv, metric, kc)
-            else:
-                cand_local, cand_d = _knn_routed(mv, metric, kc, rng,
-                                                 route_expand=route_expand)
-            n_rand = min(8, n_m - 1)
-            if n_rand > 0:
-                rnd = rng.randint(0, n_m, size=(n_m, n_rand)).astype(np.int64)
-                rnd = np.where(rnd == np.arange(n_m)[:, None],
-                               (rnd + 1) % n_m, rnd)
-                rd = _rows_dist(mv, rnd, metric)
-                cand_local = np.concatenate([cand_local, rnd], 1)
-                cand_d = np.concatenate([cand_d, rd], 1)
-                order = np.argsort(cand_d, axis=1, kind="stable")
-                cand_local = np.take_along_axis(cand_local, order, 1)
-                cand_d = np.take_along_axis(cand_d, order, 1)
+            if vecs is None:
+                vecs = _upload_rows(vectors)
+            cand_local, cand_d, counters = _knn_device(
+                vecs, members, metric, max(ef_construction, m_l + 8), rng,
+                n_m > exact_threshold, route_expand)
+            knn.set_metadata(on_device=True, **counters)
         with obs.span("hnsw.prune", **at):
             pruned_local = _diversity_prune(mv, cand_local, cand_d, m_l,
                                             metric)

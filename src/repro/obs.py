@@ -156,6 +156,12 @@ class _RecordedSpan:
         stack.append(self.index)
         return self
 
+    def set_metadata(self, **args) -> None:
+        """Add `args` to the span, as `TraceAnnotation.set_metadata` does:
+        for values known only once the span's work is done."""
+        self.args.update(args)
+        self.annotation.set_metadata(**args)
+
     def __exit__(self, *exc):
         end = time.monotonic_ns()
         self.rec._stack().pop()
@@ -167,7 +173,8 @@ class _RecordedSpan:
 
 def span(name: str, **args):
     """A context manager that marks `name` on the profiler's host clock
-    and, inside `record()`, keeps it with `args`."""
+    and, inside `record()`, keeps it with `args`.  What it enters as has
+    `set_metadata(**args)`, to add args before the span ends."""
     rec = _RECORDER.get()
     if rec is None:
         return jax.profiler.TraceAnnotation(name, **args)

@@ -138,10 +138,27 @@ def test_build_graph_blocked_spans_every_level(tiny_store):
     members = [s.args["members"] for s in children if s.name == "hnsw.knn"]
     assert members[0] == tiny_store.n > 400      # the routed path
     assert members == sorted(members, reverse=True)
+    for s in children:
+        if s.name == "hnsw.knn":
+            assert s.args["on_device"] is True and s.args["tiles"] > 0
+            assert 0.0 <= s.args["pad_share"] < 1.0
     covered = sum(s.end_ns - s.start_ns for s in children) * 1e-9
     assert covered >= 0.95 * rec.total_seconds("hnsw.build")
     assert rec.self_seconds("hnsw.knn") == pytest.approx(
         rec.total_seconds("hnsw.knn"))
+    # more rows in the same row class, another seed: the kNN compiles
+    # nothing that the first build did not
+    rng = np.random.default_rng(8)
+    more = VectorStore.build(np.concatenate([
+        np.asarray(tiny_store.vectors),
+        rng.standard_normal((37, 16)).astype(np.float32)]), metric="l2")
+    with obs.record() as again:
+        build_graph_blocked(more, m=8, ef_construction=24, seed=2,
+                            exact_threshold=400)
+    knn = {i for i, s in enumerate(again.spans) if s.name == "hnsw.knn"}
+    assert len(knn) >= 2
+    assert not [e for e in again.compiles
+                if e.stage == obs.COMPILE and e.span in knn]
 
 
 def test_graph_search_records_plan_execute_and_anytime(tiny_store):
