@@ -96,10 +96,18 @@ class BaseExecutor:
     name: str = "base"
 
     def search(self, queries, bitmaps, params: SearchParams) -> SearchResult:
+        """plan, then execute.  The result's plan keeps what was decided
+        and drops the batch it was given: a caller that keeps results
+        would otherwise keep every batch's filter bitmaps, n/8 bytes a
+        query, on the device."""
         with obs.span("executor.plan"):
             plan = self.plan(queries, bitmaps, params)
         with obs.span("executor.execute"):
-            return self.execute(plan)
+            res = self.execute(plan)
+        if res.plan is None:
+            return res
+        return dataclasses.replace(res, plan=dataclasses.replace(
+            res.plan, queries=None, bitmaps=None))
 
     def plan(self, queries, bitmaps, params: SearchParams) -> SearchPlan:
         raise NotImplementedError

@@ -6,8 +6,9 @@ analogue of the paper's "leaf packs as many vectors as fit in a page, linked
 list of pages" layout.  Optional PCA rotation precedes quantization (paper
 Table 5: PCA 1536→193 for OpenAI-5M).
 
-Search (paper Fig. 5/7): ① score branch centroids → top branches,
-② score their leaf centroids → top `num_leaves_to_search` leaves,
+Search (paper Fig. 5/7): ① score branch centroids → the nearest branches,
+as many as hold `num_leaves_to_search` leaves, ② score their leaf
+centroids → top `num_leaves_to_search` leaves,
 ③ fused filtered leaf scan (Pallas kernel): bitmap probe → dequantized
 scoring of passing rows only, ④ reordering: fetch full-precision vectors of
 the top k×reorder_factor candidates from the heap, rescore exactly, top-k.
@@ -16,6 +17,9 @@ Counters follow Table 6's ScaNN columns: filter checks = every valid row in
 every opened leaf; distance comps = rows passing filters; hops = leaves
 scanned; reorder_rows = reordering candidates; page accesses = quantized
 leaf pages + heap pages for reordering.
+
+The batched search names its stages for the profiler (`jax.named_scope`):
+`scann.select` (①②), `scann.leaf_scan` (③) and `scann.reorder` (④).
 """
 from __future__ import annotations
 
@@ -26,9 +30,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.types import (SearchParams, SearchStats, VectorStore,
                               distance, heap_pages_per_vector,
-                              probe_bitmap, sq8_quantize, topk_smallest)
+                              sq8_codes, sq8_params, topk_smallest)
 from repro.kernels import ops as kops
 from repro.storage.pages import PAGE_BYTES, scann_pages_per_leaf
 
@@ -65,125 +70,208 @@ class ScannIndex:
         return self.leaf_tiles.shape[0]
 
 
-@partial(jax.jit, static_argnames=("n",))
-def _nearest_centroid(xb: jax.Array, cent: jax.Array, n: int) -> jax.Array:
-    """Index of the L2-nearest centroid of every row: xb (B, block, d)
-    row blocks (zero-padded past row n), cent (k, d) -> (n,) int32."""
-    cn = jnp.sum(cent * cent, axis=1)
-
-    def block(x):
-        ip = jnp.matmul(x, cent.T, precision=jax.lax.Precision.HIGHEST)
-        d = jnp.sum(x * x, axis=1, keepdims=True) + cn[None, :] - 2.0 * ip
-        return jnp.argmin(d, axis=1).astype(jnp.int32)
-
-    return jax.lax.map(block, xb).reshape(-1)[:n]
+HIGHEST = jax.lax.Precision.HIGHEST
+KMEANS_BLOCK = 8192   # rows per step of a Lloyd iteration
+PACK_BLOCK = 16       # leaves per step of the SQ8 pack
 
 
-def _kmeans(x: np.ndarray, k: int, iters: int = 12, seed: int = 0,
-            block: int = 8192) -> tuple[np.ndarray, np.ndarray]:
-    """Plain Lloyd's. Returns (centroids (k, d), assignment (n,)).
-
-    The assignment step, n·k distances per iteration, runs on the default
-    device in f32; the centroid update stays on the host."""
+@partial(jax.jit, static_argnames=("block",))
+def _lloyd_step(x: jax.Array, cent: jax.Array, block: int):
+    """One Lloyd step over the rows of x (n, d), `block` rows at a time:
+    the L2-nearest centroid of every row (n,) int32, and per centroid the
+    sum (k, d) f32 and the count (k,) int32 of its rows.  The last block
+    ends at row n; the rows it shares with the block before it are
+    assigned again (to the same centroid) and summed once."""
     n, d = x.shape
+    k = cent.shape[0]
+    cn = jnp.sum(cent * cent, axis=1)
+    ids = jnp.arange(k, dtype=jnp.int32)
+
+    def step(i, carry):
+        assign, sums, counts = carry
+        lo = i * block
+        start = jnp.minimum(lo, n - block)
+        xb = jax.lax.dynamic_slice_in_dim(x, start, block)
+        ip = jnp.matmul(xb, cent.T, precision=HIGHEST)
+        dist = jnp.sum(xb * xb, axis=1, keepdims=True) + cn[None, :] - 2.0 * ip
+        a = jnp.argmin(dist, axis=1).astype(jnp.int32)
+        new = start + jnp.arange(block) >= lo
+        member = (a[:, None] == ids[None, :]) & new[:, None]      # (block, k)
+        sums = sums + jnp.matmul(member.astype(jnp.float32).T, xb,
+                                 precision=HIGHEST)
+        counts = counts + jnp.sum(member, axis=0, dtype=jnp.int32)
+        return (jax.lax.dynamic_update_slice_in_dim(assign, a, start, 0),
+                sums, counts)
+
+    return jax.lax.fori_loop(
+        0, -(-n // block), step,
+        (jnp.zeros((n,), jnp.int32), jnp.zeros((k, d), jnp.float32),
+         jnp.zeros((k,), jnp.int32)))
+
+
+def kmeans(x: jax.Array, k: int, iters: int = 12, seed: int = 0,
+           block: int = KMEANS_BLOCK):
+    """Plain Lloyd's over the rows of x (n, d) f32, on x's device: x is
+    read in place, `block` rows per step, and never copied.
+
+    Returns (centroids (k, d) f32 numpy, assignment (n,) int32 on the
+    device, counts (k,) numpy).  As in the host recipe it replaces, the
+    assignment is the last step's (made with the centroids before the
+    last update), the seeds and the reseeds of empty clusters are host
+    draws from `seed`, and a centroid is its rows' mean, divided in f64 on
+    the host; the per-centroid sums are accumulated on the device in f32
+    instead of on the host in row order."""
+    n = x.shape[0]
     rng = np.random.RandomState(seed)
-    cent = x[rng.choice(n, size=k, replace=False)].copy()
+
+    def rows(ids):
+        return np.asarray(x[jnp.asarray(ids)], np.float64)
+
+    cent = rows(rng.choice(n, size=k, replace=False))
     block = min(block, n)
-    xb = jnp.asarray(np.pad(x, ((0, (-n) % block), (0, 0)))
-                     .reshape(-1, block, d), jnp.float32)
     for _ in range(iters):
-        assign = np.asarray(_nearest_centroid(
-            xb, jnp.asarray(cent, jnp.float32), n), np.int64)
-        # per-cluster row sums, accumulated row by row in row order (the
-        # sums np.add.at gives, without its per-element dispatch)
-        cnt = np.bincount(assign, minlength=k)
-        order = np.argsort(assign, kind="stable")
-        starts = np.concatenate([[0], np.cumsum(cnt)[:-1]])
-        nz = np.flatnonzero(cnt)
-        sums = np.zeros_like(cent)
-        sums[nz] = np.add.reduceat(x[order], starts[nz], axis=0,
-                                   dtype=sums.dtype)
-        cnt = cnt.astype(np.float64)
-        empty = cnt == 0
-        cent = np.where(empty[:, None], cent,
-                        sums / np.maximum(cnt, 1)[:, None])
+        assign, sums, counts = _lloyd_step(x, jnp.asarray(cent, jnp.float32),
+                                           block)
+        counts = np.asarray(counts)
+        empty = counts == 0
+        cent = np.where(empty[:, None], cent, np.asarray(sums, np.float64)
+                        / np.maximum(counts, 1)[:, None])
         if empty.any():  # reseed empty clusters on far points
-            far = rng.choice(n, size=int(empty.sum()), replace=False)
-            cent[empty] = x[far]
-    return cent.astype(np.float32), assign
+            cent[empty] = rows(rng.choice(n, size=int(empty.sum()),
+                                          replace=False))
+    return cent.astype(np.float32), assign, counts
+
+
+@jax.jit
+def _by_cluster(assign: jax.Array) -> jax.Array:
+    """Row ids sorted by cluster, ascending within each cluster."""
+    return jnp.argsort(assign, stable=True).astype(jnp.int32)
+
+
+def _members(order: np.ndarray, counts: np.ndarray,
+             width: int) -> np.ndarray:
+    """(k, width) int32 on the host: the members of each of k clusters
+    in ascending order, -1 padded, from `_by_cluster`'s order and the
+    clusters' sizes.  Laid out here, not on the device: the device's
+    gather compiles anew for every width, which is every seed's largest
+    leaf, and its compile takes longer than this."""
+    starts = np.cumsum(counts) - counts
+    cluster = np.repeat(np.arange(len(counts)), counts)
+    out = np.full((len(counts), width), -1, np.int32)
+    out[cluster, np.arange(len(order)) - starts[cluster]] = order
+    return out
+
+
+@jax.jit
+def _pack_leaves(x: jax.Array, rowids: jax.Array, scale: jax.Array,
+                 mean: jax.Array) -> jax.Array:
+    """SQ8 tiles (L, C, d) int8 of the leaves' rows, 0 where padded,
+    PACK_BLOCK leaves per step, so that only one block of rows is ever
+    held in f32.  The last block ends at leaf L and packs again the
+    leaves it shares with the block before it."""
+    L, C = rowids.shape
+    block = min(PACK_BLOCK, L)
+
+    def step(i, tiles):
+        start = jnp.minimum(i * block, L - block)
+        r = jax.lax.dynamic_slice_in_dim(rowids, start, block)
+        q = jnp.where((r >= 0)[..., None],
+                      sq8_codes(x[jnp.maximum(r, 0)], scale, mean),
+                      jnp.int8(0))
+        return jax.lax.dynamic_update_slice_in_dim(tiles, q, start, 0)
+
+    return jax.lax.fori_loop(0, -(-L // block), step,
+                             jnp.zeros((L, C, x.shape[1]), jnp.int8))
+
+
+@jax.jit
+def _pca_moments(x: jax.Array):
+    """The rows' mean and the scatter matrix about it."""
+    mu = jnp.mean(x, axis=0)
+    xc = x - mu
+    return mu, jnp.matmul(xc.T, xc, precision=HIGHEST)
+
+
+@jax.jit
+def _project(x: jax.Array, mu: jax.Array, proj: jax.Array) -> jax.Array:
+    return jnp.matmul(x - mu, proj, precision=HIGHEST)
+
+
+def _pca(x: jax.Array, dims: int):
+    """(x projected onto its top `dims` principal axes (n, dims) on the
+    device, the axes (d, dims) and the mean (d,) as numpy)."""
+    mu, scatter = _pca_moments(x)
+    cov = np.asarray(scatter) / max(x.shape[0] - 1, 1)
+    _, v = np.linalg.eigh(cov)
+    proj = v[:, ::-1][:, :dims].astype(np.float32)
+    return _project(x, mu, jnp.asarray(proj)), proj, np.asarray(mu)
 
 
 def build_scann(store: VectorStore, num_leaves: int, levels: int = 2,
                 pca_dims: int | None = None, seed: int = 0,
                 kmeans_iters: int = 12) -> ScannIndex:
-    x = np.asarray(store.vectors, np.float32)
-    n, d = x.shape
+    """Build the index on the store's device from `store.vectors`, read in
+    place: the table is neither fetched to the host nor copied on the
+    device (with `pca_dims`, its projection (n, pca_dims) is held beside
+    it).  The host keeps what is small: the centroid updates, the leaf
+    and branch layouts (row ids, from the device's sort), and the PCA
+    basis.
 
-    if pca_dims is not None and pca_dims < d:
-        mu = x.mean(0)
-        xc = x - mu
-        cov = (xc.T @ xc) / max(n - 1, 1)
-        w, v = np.linalg.eigh(cov)
-        proj = v[:, ::-1][:, :pca_dims].astype(np.float32)
-        # fold the centering into the projection space: xp = (x - mu) @ proj
-        xp = xc @ proj
-        pca = proj
-        pca_mu = mu
-    else:
-        xp = x
-        pca = np.eye(d, dtype=np.float32)
-        pca_mu = np.zeros(d, np.float32)
-    dp = xp.shape[1]
-
-    cent, assign = _kmeans(xp, num_leaves, iters=kmeans_iters, seed=seed)
-    counts = np.bincount(assign, minlength=num_leaves)
-    cap = int(counts.max())
-    cap += (-cap) % 8  # sublane alignment
-    rowids = np.full((num_leaves, cap), -1, np.int64)
-    # rows of each leaf in ascending row order
-    order = np.argsort(assign, kind="stable")
-    leaf = assign[order]
-    starts = np.cumsum(counts) - counts
-    rowids[leaf, np.arange(n) - starts[leaf]] = order
-
-    # SQ8: per-dimension affine quantization over the dataset (the shared
-    # quantizer — the graph engine's shadow store uses the same one)
-    q, scale, mean = sq8_quantize(xp)
-    tiles = np.zeros((num_leaves, cap, dp), np.int8)
-    valid = rowids >= 0
-    tiles[valid] = q[rowids[valid]]
-
-    if levels >= 2 and num_leaves >= 16:
-        nb = max(4, int(np.sqrt(num_leaves)))
-        bcent, bassign = _kmeans(cent, nb, iters=kmeans_iters, seed=seed + 1)
-        lb = int(np.bincount(bassign, minlength=nb).max())
-        bleaves = np.full((nb, lb), -1, np.int64)
-        boffs = np.zeros(nb, np.int64)
-        for leaf in np.argsort(bassign, kind="stable"):
-            b = bassign[leaf]
-            bleaves[b, boffs[b]] = leaf
-            boffs[b] += 1
-    else:
-        levels = 1
-        bcent = np.zeros((1, dp), np.float32)
-        bleaves = np.arange(num_leaves, dtype=np.int64)[None, :]
-
-    # store the PCA mean by folding it into `mean` of the quantizer space:
-    # query projection must also subtract pca_mu — stash it in pca row space
-    # by augmenting: qp = (q - pca_mu) @ pca. We keep pca_mu separately:
-    tiles_j = jnp.asarray(tiles)
-    scale_j, mean_j = jnp.asarray(scale), jnp.asarray(mean)
-    idx = ScannIndex(
-        leaf_tiles=tiles_j,
-        leaf_rowids=jnp.asarray(rowids, jnp.int32),
-        leaf_centroids=jnp.asarray(cent),
-        scale=scale_j, mean=mean_j,
-        branch_centroids=jnp.asarray(bcent),
-        branch_leaves=jnp.asarray(bleaves, jnp.int32),
-        pca=jnp.asarray(np.concatenate([pca, pca_mu[None, :] @ pca], 0)),
-        row_norms_sq=_row_norms_sq(tiles_j, scale_j, mean_j),
-        metric=store.metric, levels=levels)
-    return idx
+    Spans (`repro.obs`): `scann.build` (args `rows`, `leaves`, `levels`)
+    holds `scann.pca` (with `pca_dims` only), `scann.kmeans` (args
+    `rows`, `leaves`, `iters`: once for the leaves and, with two levels,
+    once for the branches over the leaf centroids), `scann.pack` (the
+    leaf and branch layouts, the SQ8 range, the int8 tiles and their row
+    norms, ended once they are on the device) and `scann.upload` (the
+    host's centroids, branch layout and basis onto the device)."""
+    n, d = store.vectors.shape
+    with obs.span("scann.build", rows=n, leaves=num_leaves, levels=levels):
+        x = store.vectors
+        pca, pca_mu = np.eye(d, dtype=np.float32), np.zeros(d, np.float32)
+        if pca_dims is not None and pca_dims < d:
+            with obs.span("scann.pca", dims=pca_dims):
+                x, pca, pca_mu = jax.block_until_ready(_pca(x, pca_dims))
+        with obs.span("scann.kmeans", rows=n, leaves=num_leaves,
+                      iters=kmeans_iters):
+            cent, assign, counts = kmeans(x, num_leaves, kmeans_iters, seed)
+        if levels >= 2 and num_leaves >= 16:
+            nb = max(4, int(np.sqrt(num_leaves)))
+            with obs.span("scann.kmeans", rows=num_leaves, leaves=nb,
+                          iters=kmeans_iters):
+                bcent, bassign, bcounts = kmeans(
+                    jnp.asarray(cent), nb, kmeans_iters, seed + 1)
+        else:
+            levels = 1
+            bcent = np.zeros((1, x.shape[1]), np.float32)
+        with obs.span("scann.pack"):
+            cap = int(counts.max())
+            cap += (-cap) % 8  # sublane alignment
+            rowids = jnp.asarray(_members(np.asarray(_by_cluster(assign)),
+                                          counts, cap))
+            # SQ8: per-dimension affine quantization over the dataset (the
+            # shared quantizer: the graph engine's shadow store uses it too)
+            scale, mean = sq8_params(jnp.min(x, axis=0), jnp.max(x, axis=0))
+            tiles = _pack_leaves(x, rowids, scale, mean)
+            # one fused pass over the int8 tiles (no f32 copy of them)
+            norms = _row_norms_sq(tiles, scale, mean)
+            if levels >= 2:
+                bleaves = _members(np.asarray(_by_cluster(bassign)),
+                                   bcounts, int(bcounts.max()))
+            else:
+                bleaves = np.arange(num_leaves, dtype=np.int32)[None, :]
+            jax.block_until_ready((tiles, norms))
+        with obs.span("scann.upload"):
+            # the PCA mean rides in the projection's last row: a query is
+            # projected as q @ pca - pca_mu @ pca (project_query)
+            return jax.block_until_ready(ScannIndex(
+                leaf_tiles=tiles, leaf_rowids=rowids,
+                leaf_centroids=jnp.asarray(cent),
+                scale=scale, mean=mean,
+                branch_centroids=jnp.asarray(bcent),
+                branch_leaves=jnp.asarray(bleaves),
+                pca=jnp.asarray(np.concatenate([pca, pca_mu[None, :] @ pca],
+                                               0)),
+                row_norms_sq=norms, metric=store.metric, levels=levels))
 
 
 @jax.jit
@@ -252,6 +340,25 @@ def leaves_within_budget(index: ScannIndex, store: VectorStore,
     return 1, nl0 > 1
 
 
+def _open_branches(bd: jax.Array, branch_leaves: jax.Array, nl: int,
+                   L: int) -> jax.Array:
+    """The branches a query opens (paper Fig. 5-①), (..., B) bool from its
+    branch distances bd (..., B) over L leaves: its nb nearest, nb being
+    the number that holds 2·nl leaves where branches hold the mean, then,
+    nearest first, as many more as it takes for the opened branches to
+    hold nl leaves.  So the nl nearest leaves of the opened branches are
+    nl distinct leaves, however unevenly the branches split the leaves.
+    The counters charge B branch centroids and Lb leaf slots per opened
+    branch."""
+    B = branch_leaves.shape[0]
+    nb = min(B, max(1, -(-nl * 2 * B // L)))
+    _, order = topk_smallest(bd, B)                  # nearest first
+    held = jnp.sum(branch_leaves >= 0, axis=1)[order]
+    rank_open = (jnp.arange(B) < nb) | (jnp.cumsum(held, axis=-1) - held < nl)
+    return jnp.take_along_axis(rank_open, jnp.argsort(order, axis=-1),
+                               axis=-1)
+
+
 def _search_single(index: ScannIndex, store: VectorStore, q, bitmap,
                    params: SearchParams, use_pallas: bool):
     qp = project_query(index, q)
@@ -263,17 +370,15 @@ def _search_single(index: ScannIndex, store: VectorStore, q, bitmap,
         B, Lb = index.branch_leaves.shape
         bd = distance(index.metric, qp[None], index.branch_centroids,
                       jnp.sum(index.branch_centroids ** 2, -1))
-        # open enough branches to cover nl leaves (paper Fig. 5-①)
-        nb = min(B, max(1, -(-nl * 2 * B // L)))
-        _, bsel = topk_smallest(bd, nb)
-        cand_leaves = index.branch_leaves[bsel].reshape(-1)      # (nb*Lb,)
-        cl = jnp.maximum(cand_leaves, 0)
+        opened = _open_branches(bd, index.branch_leaves, nl, L)  # (B,)
+        cand = index.branch_leaves.reshape(-1)                    # (B*Lb,)
+        cl = jnp.maximum(cand, 0)
         ld = distance(index.metric, qp[None], index.leaf_centroids[cl],
                       jnp.sum(index.leaf_centroids[cl] ** 2, -1))
-        ld = jnp.where(cand_leaves >= 0, ld, jnp.inf)
+        ld = jnp.where((cand >= 0) & jnp.repeat(opened, Lb), ld, jnp.inf)
         _, pos = topk_smallest(ld, nl)
         leaves = cl[pos]                                          # (nl,)
-        cent_scored = index.branch_centroids.shape[0] + cand_leaves.shape[0]
+        cent_scored = B + jnp.sum(opened) * Lb
     else:
         ld = distance(index.metric, qp[None], index.leaf_centroids,
                       jnp.sum(index.leaf_centroids ** 2, -1))
@@ -353,17 +458,18 @@ def _select_leaves(index: ScannIndex, qp: jax.Array, nl: int,
         bd = kops.distance_matrix(qp, index.branch_centroids,
                                   metric=index.metric,
                                   use_pallas=use_pallas)          # (Q, B)
-        nb = min(B, max(1, -(-nl * 2 * B // L)))
-        _, bsel = topk_smallest(bd, nb)                           # (Q, nb)
-        cand = index.branch_leaves[bsel].reshape(qp.shape[0], -1)  # (Q, nb*Lb)
-        cl = jnp.maximum(cand, 0)
+        opened = _open_branches(bd, index.branch_leaves, nl, L)  # (Q, B)
+        bl = index.branch_leaves
+        branch_of = jnp.zeros((L,), jnp.int32).at[
+            jnp.where(bl >= 0, bl, L)].set(
+                jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None],
+                                 bl.shape), mode="drop")          # (L,)
         ldf = kops.distance_matrix(qp, index.leaf_centroids,
                                    metric=index.metric,
                                    use_pallas=use_pallas)         # (Q, L)
-        ld = jnp.where(cand >= 0, jnp.take_along_axis(ldf, cl, 1), jnp.inf)
-        _, pos = topk_smallest(ld, nl)
-        leaves = jnp.take_along_axis(cl, pos, 1)                  # (Q, nl)
-        return leaves, B + cand.shape[1]
+        ld = jnp.where(opened[:, branch_of], ldf, jnp.inf)
+        _, leaves = topk_smallest(ld, nl)                         # (Q, nl)
+        return leaves, B + jnp.sum(opened, axis=1) * Lb
     ld = kops.distance_matrix(qp, index.leaf_centroids,
                               metric=index.metric, use_pallas=use_pallas)
     _, leaves = topk_smallest(ld, nl)
@@ -391,7 +497,10 @@ def scann_search_batch(index: ScannIndex, store: VectorStore, queries,
     queries runs the full pipeline over its own leaf union, so the
     (Q, U, C) union-scan block — which grows ~quadratically with batch
     size when query leaf sets are disjoint — stays VMEM/HBM-bounded
-    (DESIGN.md §4 "Scaling envelope").  ids/dists are tile-size-invariant
+    (DESIGN.md §4 "Scaling envelope").  The tiles run one after another
+    (`lax.map`), so one tile's temporaries bound the memory; a last tile
+    short of B queries is filled with copies of the last query, whose
+    answers are dropped.  ids/dists are tile-size-invariant
     (each query only ever reads its own leaves' scores); "batch"
     index-page accounting amortizes per tile instead of per batch.
 
@@ -413,21 +522,35 @@ def scann_search_batch(index: ScannIndex, store: VectorStore, queries,
     if B < 0:
         raise ValueError(f"scann_query_block must be >= 0, got {B}")
     if 0 < B < Q:
-        outs = [_scann_search_block(index, store, queries[s:s + B],
-                                    bitmaps[s:s + B], params, use_pallas,
-                                    collect_trace)
-                for s in range(0, Q, B)]
-        dk = jnp.concatenate([o[0] for o in outs])
-        ids = jnp.concatenate([o[1] for o in outs])
-        stats = jax.tree.map(lambda *xs: jnp.concatenate(xs),
-                             *[o[2] for o in outs])
-        if collect_trace:
-            trace = {k: jnp.concatenate([o[3][k] for o in outs])
-                     for k in outs[0][3]}
-            return dk, ids, stats, trace
-        return dk, ids, stats
+        tiles = -(-Q // B)
+        if tiles * B > Q:       # fill the last tile with the last query
+            fill = tiles * B - Q
+            queries = jnp.concatenate(
+                [queries, jnp.repeat(queries[-1:], fill, axis=0)])
+            bitmaps = jnp.concatenate(
+                [bitmaps, jnp.repeat(bitmaps[-1:], fill, axis=0)])
+        out = jax.lax.map(
+            lambda qb: _scann_search_block(index, store, qb[0], qb[1],
+                                           params, use_pallas,
+                                           collect_trace),
+            (queries.reshape(tiles, B, -1), bitmaps.reshape(tiles, B, -1)))
+        return jax.tree.map(
+            lambda a: a.reshape((tiles * B,) + a.shape[2:])[:Q], out)
     return _scann_search_block(index, store, queries, bitmaps, params,
                                use_pallas, collect_trace)
+
+
+def _take_leaves(x: jax.Array, leaves: jax.Array) -> jax.Array:
+    """x[leaves] for a per-leaf array x (L, ...), one leaf's slab per
+    step.  The same values as the gather, which the TPU compiler lowers
+    by slicing the whole of x into temporaries (3.1 GB for the tiles of
+    3,162 leaves of 9,216 rows, by its memory analysis)."""
+    def step(i, out):
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, jax.lax.dynamic_slice_in_dim(x, leaves[i], 1, 0), i, 0)
+    return jax.lax.fori_loop(
+        0, leaves.shape[0], step,
+        jnp.zeros((leaves.shape[0],) + x.shape[1:], x.dtype))
 
 
 def _scann_search_block(index: ScannIndex, store: VectorStore, queries,
@@ -437,64 +560,68 @@ def _scann_search_block(index: ScannIndex, store: VectorStore, queries,
     Q = queries.shape[0]
     L, C, dp = index.leaf_tiles.shape
     nl = min(params.num_leaves_to_search, L)
-    qp = project_query(index, queries)                            # (Q, dp)
+    with jax.named_scope("scann.select"):
+        qp = project_query(index, queries)                        # (Q, dp)
+        leaves, cent_scored = _select_leaves(index, qp, nl, use_pallas)
 
-    leaves, cent_scored = _select_leaves(index, qp, nl, use_pallas)
+    with jax.named_scope("scann.leaf_scan"):
+        # ② union of opened leaves, each tile fetched and scored once per
+        # batch
+        cap = min(L, Q * nl)
+        uleaves, uvalid, inv = _unique_pad(leaves.reshape(-1), L, cap)
+        tiles = _take_leaves(index.leaf_tiles, uleaves)          # (U, C, dp)
+        rowids_u = jnp.where(uvalid[:, None],
+                             _take_leaves(index.leaf_rowids, uleaves), -1)
+        if index.metric == "ip":
+            norms_u = jnp.zeros((cap, C), jnp.float32)                # unused
+        elif index.row_norms_sq is not None:
+            norms_u = _take_leaves(index.row_norms_sq, uleaves)
+        else:
+            norms_u = _row_norms_sq(tiles, index.scale, index.mean)
+        scores_u = kops.leaf_scan_batched(qp, tiles, rowids_u, index.scale,
+                                          index.mean, bitmaps, norms_u,
+                                          metric=index.metric,
+                                          use_pallas=use_pallas)    # (Q, U, C)
 
-    # ② union of opened leaves — each tile fetched/scored once per batch
-    cap = min(L, Q * nl)
-    uleaves, uvalid, inv = _unique_pad(leaves.reshape(-1), L, cap)
-    tiles = index.leaf_tiles[uleaves]                             # (U, C, dp)
-    rowids_u = jnp.where(uvalid[:, None], index.leaf_rowids[uleaves], -1)
-    if index.metric == "ip":
-        norms_u = jnp.zeros((cap, C), jnp.float32)                # unused
-    elif index.row_norms_sq is not None:
-        norms_u = index.row_norms_sq[uleaves]
-    else:
-        norms_u = _row_norms_sq(tiles, index.scale, index.mean)
-    scores_u = kops.leaf_scan_batched(qp, tiles, rowids_u, index.scale,
-                                      index.mean, bitmaps, norms_u,
-                                      metric=index.metric,
-                                      use_pallas=use_pallas)      # (Q, U, C)
+        # gather each query's opened leaves back out of the union scan
+        pos_in_u = inv[leaves]                                        # (Q, nl)
+        scores = jnp.take_along_axis(scores_u, pos_in_u[:, :, None], 1)
+        rowids = rowids_u[pos_in_u]                                # (Q, nl, C)
 
-    # gather each query's opened leaves back out of the union scan
-    pos_in_u = inv[leaves]                                        # (Q, nl)
-    scores = jnp.take_along_axis(scores_u, pos_in_u[:, :, None], 1)
-    rowids = rowids_u[pos_in_u]                                   # (Q, nl, C)
+        valid = rowids >= 0
+        n_valid = valid.sum(axis=(1, 2))                              # (Q,)
+        n_pass = jnp.isfinite(scores).sum(axis=(1, 2))
 
-    valid = rowids >= 0
-    n_valid = valid.sum(axis=(1, 2))                              # (Q,)
-    n_pass = jnp.isfinite(scores).sum(axis=(1, 2))
+    with jax.named_scope("scann.reorder"):
+        # ③ per-query candidate selection (paper §6.2.2)
+        r = min(params.k * params.reorder_factor, nl * C)
+        flat_s, flat_pos = topk_smallest(scores.reshape(Q, -1), r)
+        cand_rows = jnp.take_along_axis(rowids.reshape(Q, -1), flat_pos, 1)
+        cand_ok = jnp.isfinite(flat_s) & (cand_rows >= 0)
 
-    # ③ per-query candidate selection (paper §6.2.2)
-    r = min(params.k * params.reorder_factor, nl * C)
-    flat_s, flat_pos = topk_smallest(scores.reshape(Q, -1), r)
-    cand_rows = jnp.take_along_axis(rowids.reshape(Q, -1), flat_pos, 1)
-    cand_ok = jnp.isfinite(flat_s) & (cand_rows >= 0)
-
-    # ④ full-precision reordering: the union of candidate heap rows is
-    # gathered from the store ONCE (the shared-fetch amortization), then
-    # each query rescores only its own r candidates out of the fetched
-    # block — one batched (Q, r, d) contraction at the legacy FLOP count,
-    # not Q × |union| distances.  Dedup via sort + searchsorted —
-    # O(Q·r log Q·r), independent of store.n.
-    safe_rows = jnp.maximum(cand_rows, 0)
-    rcap = min(store.n, Q * r)
-    flat = safe_rows.reshape(-1)
-    srt = jnp.sort(flat)
-    is_new = jnp.concatenate([jnp.ones((1,), bool), srt[1:] != srt[:-1]])
-    uslot = jnp.cumsum(is_new) - 1              # unique slot of each sorted id
-    urows = jnp.zeros((rcap,), jnp.int32).at[uslot].set(srt)
-    rows_u = store.vectors[urows]                                 # (rcap, d)
-    norms_u2 = store.norms_sq[urows]
-    pos = uslot[jnp.searchsorted(srt, flat)].reshape(Q, r)
-    exact = distance(store.metric, queries[:, None, :],
-                     rows_u[pos], norms_u2[pos])                  # (Q, r)
-    exact = jnp.where(cand_ok, exact, jnp.inf)
-    dk, pos = topk_smallest(exact, params.k)
-    ids = jnp.where(jnp.isinf(dk),
-                    -1, jnp.take_along_axis(cand_rows, pos, 1))
-    n_reorder = cand_ok.sum(axis=1)
+        # ④ full-precision reordering: the union of candidate heap rows is
+        # gathered from the store ONCE (the shared-fetch amortization), then
+        # each query rescores only its own r candidates out of the fetched
+        # block — one batched (Q, r, d) contraction at the legacy FLOP count,
+        # not Q × |union| distances.  Dedup via sort + searchsorted —
+        # O(Q·r log Q·r), independent of store.n.
+        safe_rows = jnp.maximum(cand_rows, 0)
+        rcap = min(store.n, Q * r)
+        flat = safe_rows.reshape(-1)
+        srt = jnp.sort(flat)
+        is_new = jnp.concatenate([jnp.ones((1,), bool), srt[1:] != srt[:-1]])
+        uslot = jnp.cumsum(is_new) - 1          # unique slot of each sorted id
+        urows = jnp.zeros((rcap,), jnp.int32).at[uslot].set(srt)
+        rows_u = store.vectors[urows]                               # (rcap, d)
+        norms_u2 = store.norms_sq[urows]
+        pos = uslot[jnp.searchsorted(srt, flat)].reshape(Q, r)
+        exact = distance(store.metric, queries[:, None, :],
+                         rows_u[pos], norms_u2[pos])                  # (Q, r)
+        exact = jnp.where(cand_ok, exact, jnp.inf)
+        dk, pos = topk_smallest(exact, params.k)
+        ids = jnp.where(jnp.isinf(dk),
+                        -1, jnp.take_along_axis(cand_rows, pos, 1))
+        n_reorder = cand_ok.sum(axis=1)
 
     # counters (Table 6 semantics, per query)
     qppl = _quant_pages_per_leaf(index)

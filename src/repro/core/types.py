@@ -85,11 +85,23 @@ def sq8_quantize(x: np.ndarray) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     dequantization x̂ = q * scale + mean.
     """
     x = np.asarray(x, np.float32)
-    lo, hi = x.min(0), x.max(0)
-    scale = np.maximum((hi - lo) / 254.0, 1e-8).astype(np.float32)
-    mean = ((hi + lo) / 2.0).astype(np.float32)
-    q = np.clip(np.round((x - mean) / scale), -127, 127).astype(np.int8)
-    return q, scale, mean
+    scale, mean = sq8_params(x.min(0), x.max(0))
+    return sq8_codes(x, scale, mean), scale, mean
+
+
+def sq8_params(lo, hi):
+    """(scale, mean) of `sq8_quantize` from the data's per-dimension
+    minimum and maximum: numpy arrays, or jax arrays for a quantizer
+    applied on the device."""
+    xp = np if isinstance(lo, np.ndarray) else jnp
+    return (xp.maximum((hi - lo) / 254.0, 1e-8).astype(np.float32),
+            ((hi + lo) / 2.0).astype(np.float32))
+
+
+def sq8_codes(x, scale, mean):
+    """int8 codes of rows `x` (numpy or jax) under `sq8_params`."""
+    xp = np if isinstance(x, np.ndarray) else jnp
+    return xp.clip(xp.round((x - mean) / scale), -127, 127).astype(np.int8)
 
 
 def quantize_store(store: "VectorStore") -> "VectorStore":
