@@ -7,10 +7,10 @@ heuristic* for pruning, plus reverse-edge augmentation.  This produces the
 same navigable-small-world topology class the paper's pgvector index has
 (M connections per node per layer, 2M at the base layer).  `build_graph`
 runs on the host and suits small stores; `build_graph_blocked`, the
-builder for large ones, computes its candidates on the device and prunes
-and links on the host.  An incremental reference builder
-(`build_incremental`) with classic insert semantics is kept for small-N
-validation tests.
+builder for large ones, computes its candidates, prunes them and fills
+the reverse edges on the device, and repairs connectivity on the host.
+An incremental reference builder (`build_incremental`) with classic
+insert semantics is kept for small-N validation tests.
 
 The graph is stored the way pgvector stores it (paper §3.1): a padded
 neighbor table per level — the TPU analogue of index pages.  Fetching row i
@@ -160,7 +160,8 @@ _PRUNE_THREADS = 8
 def _diversity_prune(vectors: np.ndarray, cand_ids: np.ndarray,
                      cand_d: np.ndarray, m: int, metric: str,
                      block: int = 4096) -> np.ndarray:
-    """HNSW select-neighbors heuristic, vectorized over nodes.
+    """HNSW select-neighbors heuristic, vectorized over nodes: `build_graph`'s
+    prune, and the host twin of `_prune_dev`.
 
     Keep candidate c (in increasing-distance order) iff it is closer to the
     node than to every already-kept neighbor.  Returns (n, m) ids, -1 padded.
@@ -418,7 +419,8 @@ def build_incremental(store: VectorStore, m: int = 16,
 # Small levels (< exact_threshold members) still use the exact kNN, so
 # upper navigation layers are identical in kind to build_graph's.  The
 # builder computes both on the device (`_knn_device`); `_knn_among` and
-# `_knn_routed` are their host twins.
+# `_knn_routed` are their host twins, as `_diversity_prune` and
+# `_augment_reverse_blocked` are of its prune and reverse fill.
 # ---------------------------------------------------------------------------
 
 def _knn_routed(mv: np.ndarray, metric: str, kc: int,
@@ -491,12 +493,12 @@ def _bucket_order(routes: np.ndarray, C: int):
 
 def _augment_reverse_blocked(level_nbrs: np.ndarray, members: np.ndarray,
                              pruned: np.ndarray, m_l: int) -> None:
-    """Vectorized reverse-edge fill: rank edges within each destination
-    group and scatter into the free slots in one shot (the per-edge
-    python loop of `_augment_reverse` is the 1M-row bottleneck).  Unlike
-    the exact twin it does not dedup against existing forward edges — a
-    repeated adjacency id only wastes the slot (the engine's visited
-    bitset dedups at traversal time)."""
+    """Vectorized reverse-edge fill, the host twin of `_reverse_dev`: rank
+    edges within each destination group and scatter into the free slots
+    in one shot (the per-edge python loop of `_augment_reverse` is the
+    1M-row bottleneck).  Unlike the exact twin it does not dedup against
+    existing forward edges — a repeated adjacency id only wastes the slot
+    (the engine's visited bitset dedups at traversal time)."""
     src = np.repeat(members, pruned.shape[1])
     dst = pruned.reshape(-1)
     ok = dst >= 0
@@ -697,21 +699,30 @@ def _upload_rows(vectors: np.ndarray) -> jax.Array:
     return jax.device_put(pad)
 
 
+def _padded_members(members: np.ndarray, P: int) -> np.ndarray:
+    """A level's rows as the device programs take them: (P,) int32, -1
+    padded."""
+    mem = np.full(P, -1, np.int32)
+    mem[:len(members)] = members
+    return mem
+
+
 def _knn_device(vecs: jax.Array, members: np.ndarray, metric: str, kc: int,
                 rng: np.random.RandomState, routed: bool, route_expand: int
-                ) -> tuple[np.ndarray, np.ndarray, dict]:
+                ) -> tuple[jax.Array, jax.Array, dict]:
     """One level's candidates: `_knn_routed` (if `routed`, drawing its
     centroids from `rng`) or `_knn_among`, then the random extras, sorted.
 
-    Returns local ids (n, min(kc, n - 1) + min(8, n - 1)) -1 padded, their
-    f32 distances (inf padded), and the counters `tiles` (query tile x
-    column tile steps run) and `pad_share` (share of the distances those
-    steps computed that were masked: padding, other buckets, self).
+    Returns, on the device, local ids (P, kc + 8) and their f32 distances,
+    ascending: row i < n holds min(kc, n - 1) + min(8, n - 1) candidates,
+    then -1 / inf padding, as do the rows past n.  Also the counters
+    `tiles` (query tile x column tile steps run) and `pad_share` (share of
+    the distances those steps computed that were masked: padding, other
+    buckets, self).
     """
     P = vecs.shape[0]
     n = len(members)
-    mem = np.full(P, -1, np.int32)
-    mem[:n] = members
+    mem = _padded_members(members, P)
     if routed:
         cent_rows, expand = _route_centroids(n, rng, route_expand, None)
         C = len(cent_rows)
@@ -748,13 +759,139 @@ def _knn_device(vecs: jax.Array, members: np.ndarray, metric: str, kc: int,
     extra[:n, :n_rand] = rnd
     d, ids = _knn_dev(vecs, mem, cols, pairs, tiles, n_qt, pair_pos, extra,
                       -(-n // _RB), metric=metric, kc=kc)
-    w = min(kc, n - 1) + n_rand
-    cand_d = np.asarray(d)[:n, :w]
-    cand = np.asarray(ids)[:n, :w].astype(np.int64)
     steps = int(tiles[1].sum())
     useful = int((np.diff(q_bounds) * np.diff(bounds)).sum()) - n
-    return cand, cand_d, {"tiles": steps,
-                          "pad_share": 1.0 - useful / (steps * _TQ * _TC)}
+    return ids, d, {"tiles": steps,
+                    "pad_share": 1.0 - useful / (steps * _TQ * _TC)}
+
+
+# ---------------------------------------------------------------------------
+# Neighbour selection on the device: the `hnsw.prune` stage and, on routed
+# levels, the reverse-edge fill of `hnsw.link`, from the candidates
+# `_knn_dev` left there.  `_prune_dev` is `_prune_block` (the keep rule at
+# HIGHEST precision, then keepPrunedConnections); `_reverse_dev` is
+# `_augment_reverse_blocked` in local ids, integer work only.  Like
+# `_knn_dev`, both depend on d, metric, widths and the row class only:
+# rows and rounds run for a traced count.
+# ---------------------------------------------------------------------------
+
+def _prune_rows(w: int, d: int) -> int:
+    """Rows per prune block: a power of two from 256 to _ROW_CLASS (so it
+    divides the padded row count) whose gathered candidate rows, (rows, w,
+    d) f32, stay within 128 MiB."""
+    fit = (128 << 20) // (4 * w * d)
+    return int(np.clip(1 << (max(fit, 1).bit_length() - 1), 256, _ROW_CLASS))
+
+
+@functools.partial(jax.jit, static_argnames=("metric", "m"))
+def _prune_dev(vecs, members, cand, cand_d, n_blocks, *, metric, m):
+    """`_diversity_prune` of a level's candidates: (P, m) local ids, -1
+    padded, and the counts of slots the keep rule and keepPrunedConnections
+    filled.  vecs (P, d) store rows; members (P,) the level's rows; cand,
+    cand_d (P, w) `_knn_dev`'s candidates; the loop runs `n_blocks` blocks
+    of `_prune_rows(w, d)` rows."""
+    P, w = cand.shape
+    rows = _prune_rows(w, vecs.shape[1])
+    col = jnp.arange(w)
+    earlier = col[None, :] < col[:, None]                 # [j, i]: i < j
+
+    def block(b, carry):
+        out, n_kept, n_fill = carry
+        cids = lax.dynamic_slice(cand, (b * rows, 0), (rows, w))
+        cd = lax.dynamic_slice(cand_d, (b * rows, 0), (rows, w))
+        cvec = vecs[jnp.maximum(members[jnp.maximum(cids, 0)], 0)]
+        cc = jax.vmap(lambda c: _dists_dev(c, c, metric))(cvec)
+
+        def keep(j, st):
+            kept, cnt = st
+            to_kept = jnp.where(kept, lax.dynamic_index_in_dim(
+                cc, j, 1, keepdims=False), jnp.inf).min(1)
+            ok = (lax.dynamic_index_in_dim(cd, j, 1, keepdims=False)
+                  < to_kept) & (cnt < m)
+            return kept | ((col == j)[None, :] & ok[:, None]), cnt + ok
+
+        kept, cnt = lax.fori_loop(0, w, keep, (jnp.zeros((rows, w), bool),
+                                               jnp.zeros(rows, jnp.int32)))
+        same = cids[:, :, None] == cids[:, None, :]
+        in_kept = (same & kept[:, None, :]).any(2)
+        repeat = (same & ~kept[:, None, :] & earlier).any(2)
+        fill = ~kept & ~in_kept & ~repeat
+        key = jnp.where(kept, col, jnp.where(fill, w + col, 2 * w))
+        pick = jnp.argsort(key, axis=1, stable=True)[:, :m]
+        sel = jnp.where(jnp.take_along_axis(key, pick, 1) < 2 * w,
+                        jnp.take_along_axis(cids, pick, 1), -1)
+        return (lax.dynamic_update_slice(out, sel, (b * rows, 0)),
+                n_kept + cnt.sum(), n_fill + (sel >= 0).sum() - cnt.sum())
+
+    zero = jnp.zeros((), jnp.int32)
+    return lax.fori_loop(0, n_blocks, block,
+                         (jnp.full((P, m), -1, jnp.int32), zero, zero))
+
+
+def _prune_device(vecs: jax.Array, members: np.ndarray, cand: jax.Array,
+                  cand_d: jax.Array, m: int, metric: str
+                  ) -> tuple[jax.Array, dict]:
+    """One level's pruned neighbours, (P, m) local ids on the device, -1
+    padded, once ready; and the counters `kept_share` and `fill_share`:
+    the shares of the level's n * m output slots filled by the keep rule
+    and by keepPrunedConnections."""
+    P, w = cand.shape
+    n = len(members)
+    pruned, kept, filled = jax.block_until_ready(_prune_dev(
+        vecs, _padded_members(members, P), cand, cand_d,
+        -(-n // _prune_rows(w, vecs.shape[1])), metric=metric, m=m))
+    slots = n * m
+    return pruned, {"kept_share": int(kept) / slots,
+                    "fill_share": int(filled) / slots}
+
+
+@jax.jit
+def _reverse_dev(members, pruned):
+    """`_augment_reverse_blocked` of a level: its adjacency (P, m) in
+    global ids, the forward edges first, then reverse edges in the free
+    slots, ranked by source within each destination; and the counts of
+    reverse edges placed and of all reverse edges.  members (P,) the
+    level's rows, -1 padded; pruned (P, m) local ids, -1 padded.
+
+    Edge e = i * m + s runs from row i to pruned[i, s], so ranking by e
+    within a destination is the host's stable sort by destination.  Round
+    r hands each destination with more than r free slots its r-th edge:
+    the least e not yet taken (a scatter-min).  The rounds run to the
+    largest number of reverse edges any node takes, none where the prune
+    filled every slot.  A 1-D sort of the edges would do the same in one
+    step, but compiles in about a minute for the TPU."""
+    P, m = pruned.shape
+    ok = pruned >= 0
+    fwd = jnp.where(ok, members[jnp.maximum(pruned, 0)], -1)
+    filled = ok.sum(1)
+    dst = jnp.where(ok, pruned, P).reshape(-1)
+    size = jnp.zeros(P, jnp.int32).at[dst].add(1, mode="drop")
+    need = jnp.minimum(size, m - filled)       # reverse edges each node takes
+    edge = jnp.arange(P * m, dtype=jnp.int32)
+    live = jnp.where(need.at[dst].get(mode="fill", fill_value=0) > 0, dst, P)
+    col = jnp.arange(m)[None, :]
+
+    def take(r, st):
+        adj, live = st
+        first = jnp.full(P, P * m, jnp.int32).at[live].min(edge, mode="drop")
+        put = (col == (filled + r)[:, None]) & (r < need)[:, None]
+        adj = jnp.where(put, members[jnp.minimum(first // m, P - 1)][:, None],
+                        adj)
+        taken = first.at[live].get(mode="fill", fill_value=-1) == edge
+        return adj, jnp.where(taken, P, live)
+
+    adj, _ = lax.fori_loop(0, need.max(), take, (fwd, live))
+    return adj, need.sum(), ok.sum()
+
+
+def _reverse_device(members: np.ndarray, pruned: jax.Array
+                    ) -> tuple[np.ndarray, int, int]:
+    """A routed level's adjacency after the reverse fill, (n, m) global
+    ids on the host, with the counts of reverse edges placed and of all
+    reverse edges."""
+    adj, placed, edges = _reverse_dev(
+        _padded_members(members, pruned.shape[0]), pruned)
+    return np.asarray(adj)[:len(members)], int(placed), int(edges)
 
 
 def build_graph_blocked(store: VectorStore, m: int = 16,
@@ -772,15 +909,24 @@ def build_graph_blocked(store: VectorStore, m: int = 16,
 
     Where each stage runs: level assignment, centroid and random-extra
     draws on the host; the candidates (routes, kNN, extras and their
-    sort) on the device (`_knn_device`); pruning, linking and repair on
-    the host.
+    sort, `_knn_device`), their pruning (`_prune_device`) and, on routed
+    levels, the reverse-edge fill (`_reverse_device`) on the device, one
+    level after another from the rows uploaded once; the exact levels'
+    reverse fill, which dedups against forward edges, and the
+    connectivity repair on the host.
 
     Spans (`repro.obs`): `hnsw.build` holds `hnsw.fetch` (vectors to the
     host), per level `hnsw.knn`, `hnsw.prune` and `hnsw.link` (args
     `level`, `members`), and `hnsw.upload` (the graph onto the device,
     ended once it is there).  `hnsw.knn` adds `on_device`, `tiles` and
     `pad_share` (`_knn_device`'s counters) and ends once the candidates
-    are on the host.
+    are ready on the device; `hnsw.prune` adds `on_device`, `kept_share`
+    and `fill_share` (`_prune_device`'s) and ends once the pruned ids are
+    ready there.  `hnsw.link` covers the reverse fill, the pull of the
+    adjacency, its write into the host table and the repair, and adds
+    `reverse_placed` and `reverse_dropped`: the reverse edges that found
+    a free slot and those that did not (a full node, or at exact levels
+    an edge already there).
     """
     with obs.span("hnsw.build"):
         with obs.span("hnsw.fetch"):
@@ -790,7 +936,7 @@ def build_graph_blocked(store: VectorStore, m: int = 16,
             exact_threshold, route_expand)
         with obs.span("hnsw.upload"):
             return jax.block_until_ready(HNSWGraph(
-                neighbors=jnp.asarray(nbrs, jnp.int32),
+                neighbors=jnp.asarray(nbrs),
                 node_level=jnp.asarray(levels, jnp.int32),
                 entry_point=jnp.asarray(entry, jnp.int32), m=m))
 
@@ -799,7 +945,8 @@ def _link_levels_blocked(vectors: np.ndarray, metric: str, m: int,
                          ef_construction: int, seed: int,
                          max_level: int | None, exact_threshold: int,
                          route_expand: int):
-    """`build_graph_blocked` on the host: (neighbors, levels, entry)."""
+    """The level loop of `build_graph_blocked`: (neighbors, levels,
+    entry) on the host."""
     n = vectors.shape[0]
     rng = np.random.RandomState(seed)
     ml = 1.0 / np.log(max(m, 2))
@@ -811,7 +958,7 @@ def _link_levels_blocked(vectors: np.ndarray, metric: str, m: int,
     top = int(levels.max())
     entry = int(np.argmax(levels))
     mmax0 = 2 * m
-    nbrs = np.full((top + 1, n, mmax0), -1, np.int64)
+    nbrs = np.full((top + 1, n, mmax0), -1, np.int32)
     vecs = None              # the rows on the device, uploaded once
 
     for lvl in range(top + 1):
@@ -822,31 +969,37 @@ def _link_levels_blocked(vectors: np.ndarray, metric: str, m: int,
         at = {"level": lvl, "members": n_m}
         m_l = mmax0 if lvl == 0 else m
         with obs.span("hnsw.knn", **at) as knn:
-            mv = vectors[members]
             if vecs is None:
                 vecs = _upload_rows(vectors)
-            cand_local, cand_d, counters = _knn_device(
+            cand, cand_d, counters = _knn_device(
                 vecs, members, metric, max(ef_construction, m_l + 8), rng,
                 n_m > exact_threshold, route_expand)
+            jax.block_until_ready((cand, cand_d))
             knn.set_metadata(on_device=True, **counters)
-        with obs.span("hnsw.prune", **at):
-            pruned_local = _diversity_prune(mv, cand_local, cand_d, m_l,
-                                            metric)
-        with obs.span("hnsw.link", **at):
-            valid = pruned_local >= 0
-            pruned = np.where(valid, members[np.clip(pruned_local, 0, None)],
-                              -1)
-            nbrs[lvl, members, :m_l] = pruned[:, :m_l]
+        with obs.span("hnsw.prune", **at) as prune:
+            pruned, shares = _prune_device(vecs, members, cand, cand_d, m_l,
+                                           metric)
+            prune.set_metadata(on_device=True, **shares)
+        with obs.span("hnsw.link", **at) as link:
             if n_m <= exact_threshold:
-                _augment_reverse(nbrs[lvl], members, pruned, m_l)
+                local = np.asarray(pruned)[:n_m]
+                fwd = np.where(local >= 0,
+                               members[np.maximum(local, 0)], -1)
+                nbrs[lvl, members, :m_l] = fwd
+                _augment_reverse(nbrs[lvl], members, fwd, m_l)
+                edges = int((fwd >= 0).sum())
+                placed = int((nbrs[lvl, members, :m_l] >= 0).sum()) - edges
             else:
-                _augment_reverse_blocked(nbrs[lvl], members, pruned, m_l)
+                adj, placed, edges = _reverse_device(members, pruned)
+                nbrs[lvl, members, :m_l] = adj
             if lvl == 0:
                 if n <= exact_threshold:
                     _repair_connectivity(nbrs[0], vectors, metric)
                 else:
                     _repair_connectivity_blocked(nbrs[0], vectors, metric,
                                                  rng)
+            link.set_metadata(reverse_placed=placed,
+                              reverse_dropped=edges - placed)
     return nbrs, levels, entry
 
 

@@ -1,6 +1,7 @@
 """The HNSW build's candidate kNN on the device against the host recipe it
 replaced: `_knn_among` for exact levels, `_knn_routed` for routed ones,
 then the random long-range extras and the stable sort of both."""
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -38,6 +39,29 @@ def _host_knn(vectors, members, metric, kc, rng, routed, route_expand):
             np.take_along_axis(dst, order, 1))
 
 
+def _on_host(ids, dst, n, kc):
+    """A level's device candidates as the host recipe returns them: the
+    first n rows, min(kc, n - 1) + min(8, n - 1) wide; the rest of the
+    padded output holds only -1 / inf."""
+    ids, dst = np.asarray(ids), np.asarray(dst)
+    assert ids.shape == dst.shape and ids.shape[1] == kc + 8
+    assert ids.shape[0] % hnsw._ROW_CLASS == 0
+    w = min(kc, n - 1) + min(8, n - 1)
+    rest = np.ones(ids.shape, bool)
+    rest[:n, :w] = False
+    assert (ids[rest] == -1).all() and np.isinf(dst[rest]).all()
+    return ids[:n, :w].astype(np.int64), dst[:n, :w]
+
+
+def _on_device(ids, dst, P, kc):
+    """Host candidates padded to the device's (P, kc + 8) shape."""
+    n, w = ids.shape
+    out_i = np.full((P, kc + 8), -1, np.int32)
+    out_d = np.full((P, kc + 8), np.inf, np.float32)
+    out_i[:n, :w], out_d[:n, :w] = ids, dst
+    return jnp.asarray(out_i), jnp.asarray(out_d)
+
+
 def _rng_state(rng):
     _, keys, pos, gauss, cached = rng.get_state()
     return keys.tolist(), pos, gauss, cached
@@ -52,6 +76,7 @@ def test_device_candidates_match_the_host(clustered, metric, routed):
                                routed, 3)
     got_i, got_d, counters = hnsw._knn_device(
         hnsw._upload_rows(clustered), members, metric, KC, dev_rng, routed, 3)
+    got_i, got_d = _on_host(got_i, got_d, N, KC)
     assert _rng_state(dev_rng) == _rng_state(host_rng)
     assert got_i.shape == want_i.shape == (N, KC + 8)
     assert got_d.dtype == np.float32
@@ -85,6 +110,7 @@ def test_device_candidates_of_a_small_level(clustered):
                      False, 3)
     got_i, got_d, _ = hnsw._knn_device(vecs, rows, "l2", KC,
                                        np.random.RandomState(1), False, 3)
+    got_i, got_d = _on_host(got_i, got_d, 13, KC)
     assert got_i.shape == (13, 12 + 8)
     assert (np.sort(got_i, 1) == np.sort(want[0], 1)).all()
     np.testing.assert_allclose(got_d, want[1], rtol=1e-5, atol=1e-4)
@@ -103,8 +129,9 @@ def test_blocked_build_matches_the_host_recipe(clustered, monkeypatch):
     got = build()
     monkeypatch.setattr(
         hnsw, "_knn_device",
-        lambda vecs, members, metric, kc, rng, routed, expand: _host_knn(
-            clustered, members, metric, kc, rng, routed, expand) + ({},))
+        lambda vecs, members, metric, kc, rng, routed, expand: _on_device(
+            *_host_knn(clustered, members, metric, kc, rng, routed, expand),
+            vecs.shape[0], kc) + ({},))
     want = build()
     assert got.shape == want.shape
     assert (got == want).mean() >= 0.99

@@ -142,12 +142,23 @@ def test_build_graph_blocked_spans_every_level(tiny_store):
         if s.name == "hnsw.knn":
             assert s.args["on_device"] is True and s.args["tiles"] > 0
             assert 0.0 <= s.args["pad_share"] < 1.0
+        if s.name == "hnsw.prune":
+            kept, fill = s.args["kept_share"], s.args["fill_share"]
+            assert s.args["on_device"] is True
+            assert 0.0 < kept <= 1.0 and 0.0 <= fill <= 1.0
+            assert kept + fill <= 1.0 + 1e-9
+        if s.name == "hnsw.link":
+            placed = s.args["reverse_placed"]
+            dropped = s.args["reverse_dropped"]
+            assert placed >= 0 and dropped >= 0
+            if s.args["level"] == 0:              # the device fill
+                assert placed + dropped > 0
     covered = sum(s.end_ns - s.start_ns for s in children) * 1e-9
     assert covered >= 0.95 * rec.total_seconds("hnsw.build")
     assert rec.self_seconds("hnsw.knn") == pytest.approx(
         rec.total_seconds("hnsw.knn"))
-    # more rows in the same row class, another seed: the kNN compiles
-    # nothing that the first build did not
+    # more rows in the same row class, another seed: the kNN, the prune
+    # and the link compile nothing that the first build did not
     rng = np.random.default_rng(8)
     more = VectorStore.build(np.concatenate([
         np.asarray(tiny_store.vectors),
@@ -155,10 +166,11 @@ def test_build_graph_blocked_spans_every_level(tiny_store):
     with obs.record() as again:
         build_graph_blocked(more, m=8, ef_construction=24, seed=2,
                             exact_threshold=400)
-    knn = {i for i, s in enumerate(again.spans) if s.name == "hnsw.knn"}
-    assert len(knn) >= 2
-    assert not [e for e in again.compiles
-                if e.stage == obs.COMPILE and e.span in knn]
+    for stage in ("hnsw.knn", "hnsw.prune", "hnsw.link"):
+        at = {i for i, s in enumerate(again.spans) if s.name == stage}
+        assert len(at) >= 2
+        assert not [e for e in again.compiles
+                    if e.stage == obs.COMPILE and e.span in at], stage
 
 
 def test_graph_search_records_plan_execute_and_anytime(tiny_store):
