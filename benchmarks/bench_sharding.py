@@ -60,7 +60,7 @@ from repro.core import (SearchParams, WorkloadSpec, filtered_knn,
 from repro.core import costmodel
 from repro.core.distributed import (ShardedGraphExecutor,
                                     make_sharded_storage)
-from repro.core.hnsw import HNSWGraph, build_graph_blocked
+from repro.core.hnsw import HNSWGraph, build_graph
 from repro.data import DatasetSpec, make_dataset_streamed
 from repro.storage import make_storage_engine
 
@@ -88,7 +88,7 @@ def _full_setup(spec: DatasetSpec, num_queries: int, f32: bool = True):
             # transiently (this is why --xl never runs in CI)
             src, _ = make_dataset_streamed(spec, num_queries=1, seed=0,
                                            f32=True, quantize=False)
-        g = build_graph_blocked(src, m=16, ef_construction=32, seed=0)
+        g = build_graph(src, m=16, ef_construction=32, seed=0)
         return (np.asarray(g.neighbors), np.asarray(g.node_level),
                 np.asarray(g.entry_point))
 

@@ -114,7 +114,7 @@ def build(seed: int, rows: int, queries: int, num_leaves: int):
     the six (selectivity, correlation) bitmap sets, all from `seed`."""
     import jax.numpy as jnp
     from repro.core import WorkloadSpec, build_scann, generate_bitmaps
-    from repro.core.hnsw import build_graph_blocked
+    from repro.core.hnsw import build_graph
     from repro.data import DatasetSpec, make_dataset_streamed
 
     t = Timer()
@@ -123,7 +123,7 @@ def build(seed: int, rows: int, queries: int, num_leaves: int):
     q = jnp.asarray(q)
     _ready(store.vectors)
     say(f"[build] data {rows}x{DIM} l2 + sq8 shadow: {t.lap():.1f} s")
-    g = _ready(build_graph_blocked(store, m=GRAPH_M, seed=seed))
+    g = _ready(build_graph(store, m=GRAPH_M, seed=seed))
     say(f"[build] hnsw graph {tuple(g.neighbors.shape)}: {t.lap():.1f} s")
     idx = _ready(build_scann(store, num_leaves=num_leaves, seed=seed))
     say(f"[build] scann leaves {tuple(idx.leaf_tiles.shape)}: "

@@ -15,8 +15,7 @@ from repro.core.bruteforce import filtered_knn, filtered_knn_partial, knn
 from repro.core.exclusion import (ExclusionIndex, build_exclusion,
                                   ladder_rung, match_families, select_radii)
 from repro.core.hnsw import (GraphPartition, HNSWGraph, PartitionedGraph,
-                             build_graph, build_graph_partitioned,
-                             build_incremental)
+                             build_graph, build_graph_partitioned)
 from repro.core.graph_search import search_batch
 from repro.core.scann import (ScannIndex, build_scann, leaves_within_budget,
                               scann_search_batch, scann_search_batch_vmapped)
@@ -48,7 +47,7 @@ __all__ = [
     "unpack_bitmap", "bitset_mark", "bitset_words", "bitset_zeros",
     "CORRELATIONS", "PAPER_SELECTIVITIES", "WorkloadSpec",
     "generate_bitmaps", "generate_grid", "generate_passing_rows",
-    "filtered_knn", "knn", "HNSWGraph", "build_graph", "build_incremental",
+    "filtered_knn", "knn", "HNSWGraph", "build_graph",
     "search_batch", "ScannIndex", "build_scann", "scann_search_batch",
     "scann_search_batch_vmapped", "LIBRARY", "SYSTEM", "CostConstants",
     "IndexShape", "cache_miss_penalty", "component_cycles",

@@ -1,16 +1,18 @@
 """HNSW-family navigable graph: construction + container.
 
-Construction here is the *deterministic, vectorizable* variant described in
-DESIGN.md §8(2): geometric level assignment exactly as HNSW, per-level kNN
-candidate generation, and the standard HNSW select-neighbors *diversity
-heuristic* for pruning, plus reverse-edge augmentation.  This produces the
-same navigable-small-world topology class the paper's pgvector index has
-(M connections per node per layer, 2M at the base layer).  `build_graph`
-runs on the host and suits small stores; `build_graph_blocked`, the
-builder for large ones, computes its candidates, prunes them and fills
-the reverse edges on the device, and repairs connectivity on the host.
-An incremental reference builder (`build_incremental`) with classic
-insert semantics is kept for small-N validation tests.
+Construction is the *deterministic, vectorizable* variant described in
+DESIGN.md §13 ("Scale path"): geometric level assignment exactly as HNSW,
+per-level kNN candidates (exact on levels of up to `_EXACT_THRESHOLD`
+members, cluster-routed on larger ones) with random long-range extras,
+the standard HNSW select-neighbors *diversity heuristic* for pruning,
+reverse-edge augmentation, and a base-layer connectivity repair.  This
+produces the same navigable-small-world topology class the paper's
+pgvector index has (M connections per node per layer, 2M at the base
+layer).  `build_graph` is the one builder, at every size: it computes
+the candidates, prunes them and fills the routed levels' reverse edges
+on the device, and repairs connectivity on the host.  The same recipe
+in numpy, the oracle it is tested against, and a classic insert-by-
+insert builder live with the tests (`tests/hnsw_host_recipe.py`).
 
 The graph is stored the way pgvector stores it (paper §3.1): a padded
 neighbor table per level — the TPU analogue of index pages.  Fetching row i
@@ -20,8 +22,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
-from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -55,8 +55,16 @@ class HNSWGraph:
 
 
 # ---------------------------------------------------------------------------
-# Vectorized construction
+# Construction: the host's part.  Levels of up to _EXACT_THRESHOLD members
+# take the exact kNN among them; larger ones (at 1M rows: levels 0 and 1)
+# route each row to its _ROUTE_EXPAND nearest of ~2√n sampled centroids and
+# take the kNN within the routed buckets (expected candidate work ≈
+# expand·n²/C), since an O(n²) kNN per level is days of work at 1M-5M rows.
 # ---------------------------------------------------------------------------
+
+_EXACT_THRESHOLD = 20_000   # members of the largest level with exact kNN
+_ROUTE_EXPAND = 3           # buckets each row of a routed level searches
+
 
 def _pairwise_dists(x: np.ndarray, y: np.ndarray, metric: str) -> np.ndarray:
     if metric == "ip":
@@ -69,219 +77,10 @@ def _pairwise_dists(x: np.ndarray, y: np.ndarray, metric: str) -> np.ndarray:
     return np.maximum(d, 0.0)
 
 
-def _knn_among(vectors: np.ndarray, metric: str, k: int,
-               block: int = 2048) -> tuple[np.ndarray, np.ndarray]:
-    """Exact kNN of each row among all rows (self excluded)."""
-    n = vectors.shape[0]
-    k = min(k, n - 1)
-    ids = np.empty((n, k), np.int64)
-    dst = np.empty((n, k), np.float32)
-    for s in range(0, n, block):
-        e = min(s + block, n)
-        d = _pairwise_dists(vectors[s:e], vectors, metric)
-        d[np.arange(e - s), np.arange(s, e)] = np.inf  # drop self
-        part = np.argpartition(d, k - 1, axis=1)[:, :k]
-        pd = np.take_along_axis(d, part, axis=1)
-        order = np.argsort(pd, axis=1)
-        ids[s:e] = np.take_along_axis(part, order, axis=1)
-        dst[s:e] = np.take_along_axis(pd, order, axis=1)
-    return ids, dst
-
-
-def _rows_dist(vectors: np.ndarray, ids: np.ndarray, metric: str) -> np.ndarray:
-    """Distance from row i to vectors[ids[i, j]] — (n, k)."""
-    x = vectors[:, None, :]
-    y = vectors[ids]
-    if metric == "ip":
-        return -np.einsum("nod,nkd->nk", x, y)[:, :]
-    if metric == "cos":
-        xn = x / (np.linalg.norm(x, axis=2, keepdims=True) + 1e-12)
-        yn = y / (np.linalg.norm(y, axis=2, keepdims=True) + 1e-12)
-        return 1.0 - np.einsum("nod,nkd->nk", xn, yn)
-    diff = y - x
-    return np.einsum("nkd,nkd->nk", diff, diff)
-
-
-def _repair_connectivity(level_nbrs: np.ndarray, vectors: np.ndarray,
-                         metric: str, max_iters: int = 64) -> None:
-    """Ensure the base layer is a single weakly-connected component.
-
-    Real HNSW graphs are connected by construction; batch construction can
-    leave rare islands.  Repair: link each minor component to its nearest
-    node in the major component (bidirectional, overwriting the last slot
-    if full).  In-place on level_nbrs.
-    """
-    n = level_nbrs.shape[0]
-    for _ in range(max_iters):
-        comp = _components(level_nbrs)
-        ids, counts = np.unique(comp, return_counts=True)
-        if len(ids) == 1:
-            return
-        major = ids[np.argmax(counts)]
-        minor = ids[ids != major][np.argmin(counts[ids != major])]
-        a_ids = np.where(comp == minor)[0]
-        b_ids = np.where(comp == major)[0]
-        # nearest cross pair (blocked if large)
-        sub = b_ids if len(b_ids) <= 20000 else \
-            b_ids[np.random.RandomState(0).choice(len(b_ids), 20000, False)]
-        d = _pairwise_dists(vectors[a_ids], vectors[sub], metric)
-        ai, bi = np.unravel_index(np.argmin(d), d.shape)
-        a, b = int(a_ids[ai]), int(sub[bi])
-        for u, v in ((a, b), (b, a)):
-            row = level_nbrs[u]
-            free = np.where(row < 0)[0]
-            row[free[0] if len(free) else len(row) - 1] = v
-
-
-def _components(level_nbrs: np.ndarray) -> np.ndarray:
-    """Weakly-connected components via union-find over the edge list."""
-    n = level_nbrs.shape[0]
-    parent = np.arange(n)
-
-    def find(x):
-        while parent[x] != x:
-            parent[x] = parent[parent[x]]
-            x = parent[x]
-        return x
-
-    src = np.repeat(np.arange(n), level_nbrs.shape[1])
-    dst = level_nbrs.reshape(-1)
-    ok = dst >= 0
-    for u, v in zip(src[ok], dst[ok]):
-        ru, rv = find(u), find(v)
-        if ru != rv:
-            parent[ru] = rv
-    return np.array([find(i) for i in range(n)])
-
-
-_PRUNE_THREADS = 8
-
-
-def _diversity_prune(vectors: np.ndarray, cand_ids: np.ndarray,
-                     cand_d: np.ndarray, m: int, metric: str,
-                     block: int = 4096) -> np.ndarray:
-    """HNSW select-neighbors heuristic, vectorized over nodes: `build_graph`'s
-    prune, and the host twin of `_prune_dev`.
-
-    Keep candidate c (in increasing-distance order) iff it is closer to the
-    node than to every already-kept neighbor.  Returns (n, m) ids, -1 padded.
-    Node blocks are independent and run on a thread pool (numpy releases
-    the interpreter lock in these kernels); the result does not depend on
-    the thread count.  Each running block holds about 250 MB at 4096
-    nodes, 48 candidates and d=128, so the pool is capped at
-    `_PRUNE_THREADS` whatever the host's core count.
-    """
-    n = cand_ids.shape[0]
-    out = np.full((n, m), -1, np.int64)
-    starts = range(0, n, block)
-    workers = max(1, min(len(starts), len(os.sched_getaffinity(0)),
-                         _PRUNE_THREADS))
-    with ThreadPoolExecutor(max_workers=workers) as pool:
-        for s, sel in zip(starts, pool.map(
-                lambda s: _prune_block(vectors, cand_ids[s:s + block],
-                                       cand_d[s:s + block], m, metric),
-                starts)):
-            out[s:s + block, :sel.shape[1]] = sel
-    return out
-
-
-def _prune_block(vectors: np.ndarray, cids: np.ndarray, cd: np.ndarray,
-                 m: int, metric: str) -> np.ndarray:
-    """`_diversity_prune` of one node block: (b, kc) candidates (ids and
-    distances to the node, ascending) -> (b, min(m, kc)) ids, -1 padded."""
-    b, kc = cids.shape
-    cvec = vectors[cids]                           # (b, kc, d)
-    # pairwise distances between candidates of the same node: (b, kc, kc)
-    if metric == "ip":
-        cc = -np.einsum("bid,bjd->bij", cvec, cvec)
-    elif metric == "cos":
-        cn = cvec / (np.linalg.norm(cvec, axis=2, keepdims=True) + 1e-12)
-        cc = 1.0 - np.einsum("bid,bjd->bij", cn, cn)
-    else:
-        sq = (cvec * cvec).sum(2)
-        cc = sq[:, :, None] + sq[:, None, :] - 2.0 * np.einsum(
-            "bid,bjd->bij", cvec, cvec)
-    kept = np.zeros((b, kc), bool)
-    kept_cnt = np.zeros(b, np.int64)
-    for j in range(kc):
-        # distance from candidate j to every kept candidate
-        d_to_kept = np.where(kept, cc[:, j, :], np.inf)
-        ok = (cd[:, j] < d_to_kept.min(axis=1)) & (kept_cnt < m)
-        kept[:, j] = ok
-        kept_cnt += ok
-    # keepPrunedConnections (standard HNSW): slots left free take the
-    # closest pruned candidates, skipping ids already selected.  Kept
-    # candidates come first, then the pruned ones in distance order.
-    same = cids[:, :, None] == cids[:, None, :]               # (b, kc, kc)
-    in_kept = (same & kept[:, None, :]).any(2)
-    earlier = np.tril(np.ones((kc, kc), bool), -1)            # [j, i]: i < j
-    repeat = (same & ~kept[:, None, :] & earlier).any(2)
-    fill = ~kept & ~in_kept & ~repeat
-    col = np.arange(kc)
-    key = np.where(kept, col, np.where(fill, kc + col, 2 * kc))
-    pick = np.argsort(key, axis=1, kind="stable")[:, :m]
-    return np.where(np.take_along_axis(key, pick, 1) < 2 * kc,
-                    np.take_along_axis(cids, pick, 1), -1)
-
-
-def build_graph(store: VectorStore, m: int = 16, ef_construction: int = 64,
-                seed: int = 0, max_level: int | None = None) -> HNSWGraph:
-    vectors = np.asarray(store.vectors)
-    n = vectors.shape[0]
-    rng = np.random.RandomState(seed)
-    ml = 1.0 / np.log(max(m, 2))
-    levels = np.minimum(
-        np.floor(-np.log(rng.uniform(1e-12, 1.0, n)) * ml).astype(np.int64),
-        12)
-    if max_level is not None:
-        levels = np.minimum(levels, max_level)
-    top = int(levels.max())
-    entry = int(np.argmax(levels))
-    mmax0 = 2 * m
-    nbrs = np.full((top + 1, n, mmax0), -1, np.int64)
-
-    for lvl in range(top + 1):
-        members = np.where(levels >= lvl)[0]
-        if len(members) <= 1:
-            continue
-        mv = vectors[members]
-        m_l = mmax0 if lvl == 0 else m
-        kc = min(max(ef_construction, m_l + 8), len(members) - 1)
-        cand_local, cand_d = _knn_among(mv, store.metric, kc)
-        # Long-range candidates (NSW semantics): real HNSW's insertion search
-        # exposes far nodes to the pruning heuristic, which keeps a few long
-        # edges for navigability.  We reproduce that by appending random
-        # candidates before pruning.
-        n_m = len(members)
-        n_rand = min(8, n_m - 1)
-        if n_rand > 0:
-            rnd = rng.randint(0, n_m, size=(n_m, n_rand)).astype(np.int64)
-            rnd = np.where(rnd == np.arange(n_m)[:, None],
-                           (rnd + 1) % n_m, rnd)
-            rd = _rows_dist(mv, rnd, store.metric)
-            cand_local = np.concatenate([cand_local, rnd], 1)
-            cand_d = np.concatenate([cand_d, rd], 1)
-            order = np.argsort(cand_d, axis=1, kind="stable")
-            cand_local = np.take_along_axis(cand_local, order, 1)
-            cand_d = np.take_along_axis(cand_d, order, 1)
-        pruned_local = _diversity_prune(mv, cand_local, cand_d, m_l, store.metric)
-        # map local ids back to global
-        valid = pruned_local >= 0
-        pruned = np.where(valid, members[np.clip(pruned_local, 0, None)], -1)
-        nbrs[lvl, members, :m_l] = pruned[:, :m_l]
-        # reverse-edge augmentation: fill free slots with reverse links
-        _augment_reverse(nbrs[lvl], members, pruned, m_l)
-        if lvl == 0:
-            _repair_connectivity(nbrs[0], vectors, store.metric)
-
-    return HNSWGraph(neighbors=jnp.asarray(nbrs, jnp.int32),
-                     node_level=jnp.asarray(levels, jnp.int32),
-                     entry_point=jnp.asarray(entry, jnp.int32), m=m)
-
-
 def _augment_reverse(level_nbrs: np.ndarray, members: np.ndarray,
                      pruned: np.ndarray, m_l: int) -> None:
-    """Add reverse edges into free (-1) slots, capped at m_l per node."""
+    """The exact levels' reverse fill: add reverse edges into free (-1)
+    slots, capped at m_l per node, skipping an edge already there."""
     src = np.repeat(members, pruned.shape[1])
     dst = pruned.reshape(-1)
     ok = dst >= 0
@@ -295,184 +94,12 @@ def _augment_reverse(level_nbrs: np.ndarray, members: np.ndarray,
             counts[d] += 1
 
 
-# ---------------------------------------------------------------------------
-# Incremental reference builder (classic HNSW inserts) — small N only.
-# ---------------------------------------------------------------------------
-
-def build_incremental(store: VectorStore, m: int = 16,
-                      ef_construction: int = 64, seed: int = 0) -> HNSWGraph:
-    vectors = np.asarray(store.vectors)
-    n = vectors.shape[0]
-    rng = np.random.RandomState(seed)
-    ml = 1.0 / np.log(max(m, 2))
-    levels = np.minimum(
-        np.floor(-np.log(rng.uniform(1e-12, 1.0, n)) * ml).astype(np.int64), 12)
-    top = int(levels.max())
-    mmax0 = 2 * m
-    nbrs = np.full((top + 1, n, mmax0), -1, np.int64)
-    metric = store.metric
-
-    def dist(a, b_ids):
-        return _pairwise_dists(vectors[a][None], vectors[b_ids], metric)[0]
-
-    def greedy(q, entry, lvl):
-        cur, cur_d = entry, dist(q, np.array([entry]))[0]
-        while True:
-            nb = nbrs[lvl, cur]
-            nb = nb[nb >= 0]
-            if len(nb) == 0:
-                return cur
-            ds = dist(q, nb)
-            j = int(np.argmin(ds))
-            if ds[j] < cur_d:
-                cur, cur_d = int(nb[j]), float(ds[j])
-            else:
-                return cur
-
-    def search_layer(q, entry, lvl, ef):
-        visited = {entry}
-        ds0 = float(dist(q, np.array([entry]))[0])
-        cand = [(ds0, entry)]
-        result = [(ds0, entry)]
-        while cand:
-            cand.sort()
-            d_c, c = cand.pop(0)
-            result.sort()
-            if d_c > result[min(len(result), ef) - 1][0] and len(result) >= ef:
-                break
-            nb = nbrs[lvl, c]
-            nb = [int(x) for x in nb[nb >= 0] if int(x) not in visited]
-            if not nb:
-                continue
-            visited.update(nb)
-            ds = dist(q, np.array(nb))
-            worst = result[min(len(result), ef) - 1][0]
-            for dd, node in zip(ds, nb):
-                if len(result) < ef or dd < worst:
-                    cand.append((float(dd), node))
-                    result.append((float(dd), node))
-                    result.sort()
-                    result = result[:ef]
-                    worst = result[-1][0]
-        return result
-
-    def select(q_id, cand_pairs, m_l):
-        cand_pairs = sorted(cand_pairs)
-        kept: list[int] = []
-        for d_c, c in cand_pairs:
-            if len(kept) >= m_l:
-                break
-            if all(_pairwise_dists(vectors[c][None], vectors[np.array([k])],
-                                   metric)[0, 0] > d_c for k in kept):
-                kept.append(c)
-        return kept
-
-    entry = 0
-    entry_level = int(levels[0])
-    for i in range(1, n):
-        lvl_i = int(levels[i])
-        ep = entry
-        for lvl in range(entry_level, lvl_i, -1):
-            ep = greedy(i, ep, min(lvl, entry_level))
-        for lvl in range(min(lvl_i, entry_level), -1, -1):
-            res = search_layer(i, ep, lvl, ef_construction)
-            m_l = mmax0 if lvl == 0 else m
-            sel = select(i, res, m_l)
-            nbrs[lvl, i, : len(sel)] = sel
-            for s in sel:
-                cur = nbrs[lvl, s]
-                free = np.where(cur < 0)[0]
-                if len(free):
-                    cur[free[0]] = i
-                else:
-                    # re-prune neighbor's list with i included
-                    cand = [(float(_pairwise_dists(vectors[s][None],
-                                                   vectors[np.array([c])],
-                                                   metric)[0, 0]), int(c))
-                            for c in cur] + [
-                        (float(_pairwise_dists(vectors[s][None],
-                                               vectors[np.array([i])],
-                                               metric)[0, 0]), i)]
-                    sel2 = select(s, cand, m_l)
-                    cur[:] = -1
-                    cur[: len(sel2)] = sel2
-            ep = res[0][1]
-        if lvl_i > entry_level:
-            entry, entry_level = i, lvl_i
-
-    return HNSWGraph(neighbors=jnp.asarray(nbrs, jnp.int32),
-                     node_level=jnp.asarray(levels, jnp.int32),
-                     entry_point=jnp.asarray(entry, jnp.int32), m=m)
-
-
-# ---------------------------------------------------------------------------
-# Blocked (cluster-routed) construction — the >=1M-row path (DESIGN.md §13).
-#
-# `build_graph`'s per-level exact kNN is O(n²) per level; at the sharding
-# bench's operating point (1M-5M × 768) that is days of single-core work.
-# The blocked builder keeps the construction *recipe* — geometric levels,
-# long-range candidates, diversity pruning, reverse augmentation,
-# base-layer connectivity repair — and replaces only the candidate
-# generation on large levels with cluster routing: rows route to their
-# `route_expand` nearest of ~2√n sampled centroids and take exact kNN
-# within the routed buckets (expected candidate work ≈ expand·n²/C).
-# Small levels (< exact_threshold members) still use the exact kNN, so
-# upper navigation layers are identical in kind to build_graph's.  The
-# builder computes both on the device (`_knn_device`); `_knn_among` and
-# `_knn_routed` are their host twins, as `_diversity_prune` and
-# `_augment_reverse_blocked` are of its prune and reverse fill.
-# ---------------------------------------------------------------------------
-
-def _knn_routed(mv: np.ndarray, metric: str, kc: int,
-                rng: np.random.RandomState, route_expand: int = 3,
-                num_centroids: int | None = None
-                ) -> tuple[np.ndarray, np.ndarray]:
-    """Approximate kNN among rows via sampled-centroid bucket routing."""
-    n = mv.shape[0]
-    kc = min(kc, n - 1)
-    cent_rows, expand = _route_centroids(n, rng, route_expand, num_centroids)
-    C = len(cent_rows)
-    cents = mv[cent_rows]
-    routes = np.empty((n, expand), np.int64)
-    for s in range(0, n, 8192):
-        e = min(s + 8192, n)
-        d = _pairwise_dists(mv[s:e], cents, metric)
-        routes[s:e] = np.argpartition(d, expand - 1, axis=1)[:, :expand]
-    order, bounds, q_order, q_bounds = _bucket_order(routes, C)
-    q_rows = q_order // expand
-    ids = np.full((n, kc), -1, np.int64)
-    dst = np.full((n, kc), np.inf, np.float32)
-    for c in range(C):
-        grp = order[bounds[c]:bounds[c + 1]]
-        qr = q_rows[q_bounds[c]:q_bounds[c + 1]]
-        if len(grp) == 0 or len(qr) == 0:
-            continue
-        d = _pairwise_dists(mv[qr], mv[grp], metric)
-        d[qr[:, None] == grp[None, :]] = np.inf      # drop self
-        t = min(kc, len(grp))
-        part = np.argpartition(d, t - 1, axis=1)[:, :t]
-        pd = np.take_along_axis(d, part, axis=1).astype(np.float32)
-        # merge bucket top-t into the running per-row top-kc
-        cat_d = np.concatenate([dst[qr], pd], axis=1)
-        cat_i = np.concatenate([ids[qr], grp[part]], axis=1)
-        sel = np.argpartition(cat_d, kc - 1, axis=1)[:, :kc]
-        sd = np.take_along_axis(cat_d, sel, axis=1)
-        si = np.take_along_axis(cat_i, sel, axis=1)
-        o = np.argsort(sd, axis=1, kind="stable")
-        dst[qr] = np.take_along_axis(sd, o, axis=1)
-        ids[qr] = np.take_along_axis(si, o, axis=1)
-    # a row's routes are distinct buckets, and buckets partition the rows,
-    # so no candidate repeats
-    return ids, dst
-
-
-def _route_centroids(n: int, rng: np.random.RandomState, route_expand: int,
-                     num_centroids: int | None) -> tuple[np.ndarray, int]:
+def _route_centroids(n: int, rng: np.random.RandomState
+                     ) -> tuple[np.ndarray, int]:
     """The rows drawn as centroids (~2√n of them), and how many of them
     each row routes to."""
-    C = num_centroids or int(np.clip(2 * np.sqrt(n), 64, 4096))
-    C = min(C, n)
-    return rng.choice(n, C, replace=False), min(route_expand, C)
+    C = min(int(np.clip(2 * np.sqrt(n), 64, 4096)), n)
+    return rng.choice(n, C, replace=False), min(_ROUTE_EXPAND, C)
 
 
 def _bucket_order(routes: np.ndarray, C: int):
@@ -491,38 +118,18 @@ def _bucket_order(routes: np.ndarray, C: int):
     return order, bounds, q_order, q_bounds
 
 
-def _augment_reverse_blocked(level_nbrs: np.ndarray, members: np.ndarray,
-                             pruned: np.ndarray, m_l: int) -> None:
-    """Vectorized reverse-edge fill, the host twin of `_reverse_dev`: rank
-    edges within each destination group and scatter into the free slots
-    in one shot (the per-edge python loop of `_augment_reverse` is the
-    1M-row bottleneck).  Unlike the exact twin it does not dedup against
-    existing forward edges — a repeated adjacency id only wastes the slot
-    (the engine's visited bitset dedups at traversal time)."""
-    src = np.repeat(members, pruned.shape[1])
-    dst = pruned.reshape(-1)
-    ok = dst >= 0
-    src, dst = src[ok], dst[ok]
-    if len(dst) == 0:
-        return
-    order = np.argsort(dst, kind="stable")
-    src, dst = src[order], dst[order]
-    first = np.concatenate([[True], dst[1:] != dst[:-1]])
-    grp_start = np.flatnonzero(first)
-    rank = np.arange(len(dst)) - grp_start[np.cumsum(first) - 1]
-    slot = (level_nbrs[dst, :m_l] >= 0).sum(1) + rank
-    keep = slot < m_l
-    level_nbrs[dst[keep], slot[keep]] = src[keep]
+def _repair_connectivity(level_nbrs: np.ndarray, vectors: np.ndarray,
+                         metric: str, rng: np.random.RandomState,
+                         max_iters: int = 16) -> None:
+    """Ensure the base layer is a single weakly-connected component.
 
-
-def _repair_connectivity_blocked(level_nbrs: np.ndarray,
-                                 vectors: np.ndarray, metric: str,
-                                 rng: np.random.RandomState,
-                                 max_iters: int = 16) -> None:
-    """scipy-csgraph twin of `_repair_connectivity`: one sparse
-    connected-components pass links EVERY minor component to the major
-    one per iteration (the union-find python loop is quadratic-ish in
-    practice at 1M rows)."""
+    Real HNSW graphs are connected by construction; batch construction can
+    leave rare islands.  Repair: one sparse connected-components pass,
+    then link EVERY minor component to its nearest node in the major one
+    (bidirectional, overwriting the last slot if full); at most
+    `max_iters` passes, until one component is left.  Components of more than 20,000 (major) or
+    4,096 (minor) rows are sampled from `rng`.  In-place on level_nbrs.
+    """
     import scipy.sparse as sp
     from scipy.sparse.csgraph import connected_components
     n = level_nbrs.shape[0]
@@ -555,9 +162,9 @@ def _repair_connectivity_blocked(level_nbrs: np.ndarray,
 
 # ---------------------------------------------------------------------------
 # Candidate generation on the device: the `hnsw.knn` stage of
-# `build_graph_blocked`.  The same candidates as `_knn_among` (levels of up
-# to exact_threshold members) and `_knn_routed` (larger levels), plus the
-# random long-range extras and the sort of both, from two jitted programs:
+# `build_graph`.  The exact kNN (levels of up to _EXACT_THRESHOLD members)
+# or the routed one (larger levels), plus the random long-range extras and
+# the sort of both, from two jitted programs:
 # f32 distances at HIGHEST precision, exact `lax.top_k`.  The centroids,
 # the extras and the grouping of (row, route) pairs by bucket are drawn on
 # the host, so the build consumes the same rng stream.  An exact level is
@@ -589,7 +196,8 @@ def _dists_dev(x: jax.Array, y: jax.Array, metric: str) -> jax.Array:
 
 
 def _rows_dist_dev(x: jax.Array, y: jax.Array, metric: str) -> jax.Array:
-    """`_rows_dist` on the device: x (b, d) against y (b, k, d) -> (b, k)."""
+    """Distance from x[i] to each y[i, j]: x (b, d) against y (b, k, d)
+    -> (b, k)."""
     x = x[:, None, :]
     if metric == "ip":
         return -(x * y).sum(2)
@@ -708,10 +316,11 @@ def _padded_members(members: np.ndarray, P: int) -> np.ndarray:
 
 
 def _knn_device(vecs: jax.Array, members: np.ndarray, metric: str, kc: int,
-                rng: np.random.RandomState, routed: bool, route_expand: int
+                rng: np.random.RandomState, routed: bool
                 ) -> tuple[jax.Array, jax.Array, dict]:
-    """One level's candidates: `_knn_routed` (if `routed`, drawing its
-    centroids from `rng`) or `_knn_among`, then the random extras, sorted.
+    """One level's candidates: the exact kNN among its members or, if
+    `routed`, the kNN within each row's routed buckets (drawing the
+    centroids from `rng`); then the random extras, sorted.
 
     Returns, on the device, local ids (P, kc + 8) and their f32 distances,
     ascending: row i < n holds min(kc, n - 1) + min(8, n - 1) candidates,
@@ -724,7 +333,7 @@ def _knn_device(vecs: jax.Array, members: np.ndarray, metric: str, kc: int,
     n = len(members)
     mem = _padded_members(members, P)
     if routed:
-        cent_rows, expand = _route_centroids(n, rng, route_expand, None)
+        cent_rows, expand = _route_centroids(n, rng)
         C = len(cent_rows)
         cents = np.full(max(64, 1 << (C - 1).bit_length()), -1, np.int32)
         cents[:C] = members[cent_rows]
@@ -768,9 +377,10 @@ def _knn_device(vecs: jax.Array, members: np.ndarray, metric: str, kc: int,
 # ---------------------------------------------------------------------------
 # Neighbour selection on the device: the `hnsw.prune` stage and, on routed
 # levels, the reverse-edge fill of `hnsw.link`, from the candidates
-# `_knn_dev` left there.  `_prune_dev` is `_prune_block` (the keep rule at
-# HIGHEST precision, then keepPrunedConnections); `_reverse_dev` is
-# `_augment_reverse_blocked` in local ids, integer work only.  Like
+# `_knn_dev` left there.  `_prune_dev` is the HNSW select-neighbors
+# heuristic (the keep rule at HIGHEST precision, then
+# keepPrunedConnections); `_reverse_dev` ranks each node's reverse edges by
+# source, in local ids, integer work only.  Like
 # `_knn_dev`, both depend on d, metric, widths and the row class only:
 # rows and rounds run for a traced count.
 # ---------------------------------------------------------------------------
@@ -785,11 +395,17 @@ def _prune_rows(w: int, d: int) -> int:
 
 @functools.partial(jax.jit, static_argnames=("metric", "m"))
 def _prune_dev(vecs, members, cand, cand_d, n_blocks, *, metric, m):
-    """`_diversity_prune` of a level's candidates: (P, m) local ids, -1
-    padded, and the counts of slots the keep rule and keepPrunedConnections
-    filled.  vecs (P, d) store rows; members (P,) the level's rows; cand,
-    cand_d (P, w) `_knn_dev`'s candidates; the loop runs `n_blocks` blocks
-    of `_prune_rows(w, d)` rows."""
+    """The HNSW select-neighbors heuristic over a level's candidates:
+    (P, m) local ids, -1 padded, and the counts of slots the keep rule and
+    keepPrunedConnections filled.  vecs (P, d) store rows; members (P,)
+    the level's rows; cand, cand_d (P, w) `_knn_dev`'s candidates; the
+    loop runs `n_blocks` blocks of `_prune_rows(w, d)` rows.
+
+    Keep candidate c (in increasing-distance order) iff it is closer to
+    the node than to every already-kept neighbour, up to m of them; then
+    keepPrunedConnections (standard HNSW): slots left free take the
+    closest pruned candidates, skipping ids already selected.  Kept
+    candidates come first, then the pruned ones in distance order."""
     P, w = cand.shape
     rows = _prune_rows(w, vecs.shape[1])
     col = jnp.arange(w)
@@ -847,14 +463,17 @@ def _prune_device(vecs: jax.Array, members: np.ndarray, cand: jax.Array,
 
 @jax.jit
 def _reverse_dev(members, pruned):
-    """`_augment_reverse_blocked` of a level: its adjacency (P, m) in
-    global ids, the forward edges first, then reverse edges in the free
-    slots, ranked by source within each destination; and the counts of
-    reverse edges placed and of all reverse edges.  members (P,) the
-    level's rows, -1 padded; pruned (P, m) local ids, -1 padded.
+    """The reverse fill of a routed level: its adjacency (P, m) in global
+    ids, the forward edges first, then reverse edges in the free slots,
+    ranked by source within each destination; and the counts of reverse
+    edges placed and of all reverse edges.  members (P,) the level's rows,
+    -1 padded; pruned (P, m) local ids, -1 padded.  Unlike
+    `_augment_reverse` it does not dedup against the forward edges: a
+    repeated adjacency id only wastes the slot (the engine's visited
+    bitset dedups at traversal time).
 
     Edge e = i * m + s runs from row i to pruned[i, s], so ranking by e
-    within a destination is the host's stable sort by destination.  Round
+    within a destination is a stable sort by destination.  Round
     r hands each destination with more than r free slots its r-th edge:
     the least e not yet taken (a scatter-min).  The rounds run to the
     largest number of reverse edges any node takes, none where the prune
@@ -894,18 +513,17 @@ def _reverse_device(members: np.ndarray, pruned: jax.Array
     return np.asarray(adj)[:len(members)], int(placed), int(edges)
 
 
-def build_graph_blocked(store: VectorStore, m: int = 16,
-                        ef_construction: int = 32, seed: int = 0,
-                        max_level: int | None = None,
-                        exact_threshold: int = 20_000,
-                        route_expand: int = 3) -> HNSWGraph:
-    """`build_graph` recipe with cluster-routed candidates on big levels.
+def build_graph(store: VectorStore, m: int = 16, ef_construction: int = 32,
+                seed: int = 0, max_level: int | None = None) -> HNSWGraph:
+    """The HNSW graph of `store`: M neighbours per node per level, 2M at
+    level 0, from `ef_construction` (at least that level's M + 8)
+    candidates each.
 
-    Levels with <= `exact_threshold` members take the exact kNN of
-    `_knn_among`; larger levels (at 1M rows: levels 0 and 1) take the
-    candidates of `_knn_routed` and swap the python-loop reverse/repair
-    passes for their vectorized twins.  Same topology class, not
-    bit-identical to `build_graph`.
+    Levels with <= `_EXACT_THRESHOLD` members take the exact kNN among
+    their members; larger levels (at 1M rows: levels 0 and 1) take the
+    kNN within each row's `_ROUTE_EXPAND` nearest buckets.  The same
+    graph, up to near-ties, as the host recipe of
+    `tests/hnsw_host_recipe.py` where every level is exact.
 
     Where each stage runs: level assignment, centroid and random-extra
     draws on the host; the candidates (routes, kNN, extras and their
@@ -931,9 +549,8 @@ def build_graph_blocked(store: VectorStore, m: int = 16,
     with obs.span("hnsw.build"):
         with obs.span("hnsw.fetch"):
             vectors = np.asarray(store.vectors)
-        nbrs, levels, entry = _link_levels_blocked(
-            vectors, store.metric, m, ef_construction, seed, max_level,
-            exact_threshold, route_expand)
+        nbrs, levels, entry = _link_levels(
+            vectors, store.metric, m, ef_construction, seed, max_level)
         with obs.span("hnsw.upload"):
             return jax.block_until_ready(HNSWGraph(
                 neighbors=jnp.asarray(nbrs),
@@ -941,12 +558,15 @@ def build_graph_blocked(store: VectorStore, m: int = 16,
                 entry_point=jnp.asarray(entry, jnp.int32), m=m))
 
 
-def _link_levels_blocked(vectors: np.ndarray, metric: str, m: int,
-                         ef_construction: int, seed: int,
-                         max_level: int | None, exact_threshold: int,
-                         route_expand: int):
-    """The level loop of `build_graph_blocked`: (neighbors, levels,
-    entry) on the host."""
+# The benchmark's HNSW index (`fvsbench/indexes/hnsw.py`) imports the
+# builder under this name; it is `build_graph` itself.
+build_graph_blocked = build_graph
+
+
+def _link_levels(vectors: np.ndarray, metric: str, m: int,
+                 ef_construction: int, seed: int, max_level: int | None):
+    """The level loop of `build_graph`: (neighbors, levels, entry) on the
+    host."""
     n = vectors.shape[0]
     rng = np.random.RandomState(seed)
     ml = 1.0 / np.log(max(m, 2))
@@ -973,7 +593,7 @@ def _link_levels_blocked(vectors: np.ndarray, metric: str, m: int,
                 vecs = _upload_rows(vectors)
             cand, cand_d, counters = _knn_device(
                 vecs, members, metric, max(ef_construction, m_l + 8), rng,
-                n_m > exact_threshold, route_expand)
+                n_m > _EXACT_THRESHOLD)
             jax.block_until_ready((cand, cand_d))
             knn.set_metadata(on_device=True, **counters)
         with obs.span("hnsw.prune", **at) as prune:
@@ -981,7 +601,7 @@ def _link_levels_blocked(vectors: np.ndarray, metric: str, m: int,
                                            metric)
             prune.set_metadata(on_device=True, **shares)
         with obs.span("hnsw.link", **at) as link:
-            if n_m <= exact_threshold:
+            if n_m <= _EXACT_THRESHOLD:
                 local = np.asarray(pruned)[:n_m]
                 fwd = np.where(local >= 0,
                                members[np.maximum(local, 0)], -1)
@@ -993,11 +613,7 @@ def _link_levels_blocked(vectors: np.ndarray, metric: str, m: int,
                 adj, placed, edges = _reverse_device(members, pruned)
                 nbrs[lvl, members, :m_l] = adj
             if lvl == 0:
-                if n <= exact_threshold:
-                    _repair_connectivity(nbrs[0], vectors, metric)
-                else:
-                    _repair_connectivity_blocked(nbrs[0], vectors, metric,
-                                                 rng)
+                _repair_connectivity(nbrs[0], vectors, metric, rng)
             link.set_metadata(reverse_placed=placed,
                               reverse_dropped=edges - placed)
     return nbrs, levels, entry
@@ -1070,18 +686,15 @@ class PartitionedGraph:
 
 def build_graph_partitioned(store: VectorStore,
                             families: dict[str, np.ndarray], m: int = 16,
-                            ef_construction: int = 32, seed: int = 0,
-                            blocked_threshold: int = 20_000
+                            ef_construction: int = 32, seed: int = 0
                             ) -> PartitionedGraph:
     """Build one subgraph per predicate family (JAG tier, DESIGN.md §14).
 
     families maps tag -> packed (W,) uint32 bitmap over the store's rows.
     Each family's passing rows are gathered into a dense sub-store
     (carrying the SQ8 shadow rows verbatim when present, so quantized
-    traversal works unchanged) and indexed with the same recipe as the
-    base graph — `build_graph` below `blocked_threshold` rows, the
-    cluster-routed `build_graph_blocked` above it (the PR-9 builder that
-    scales past the toy grids).
+    traversal works unchanged) and indexed by `build_graph`, the same
+    recipe as the base graph.
     """
     from repro.core.types import unpack_bitmap
     n = store.n
@@ -1093,9 +706,8 @@ def build_graph_partitioned(store: VectorStore,
             raise ValueError(f"family {tag!r} has {rows.size} passing "
                              "rows; a subgraph needs at least 2")
         sub = gather_substore(store, rows)
-        build = (build_graph if rows.size <= blocked_threshold
-                 else build_graph_blocked)
-        g = build(sub, m=m, ef_construction=ef_construction, seed=seed + i)
+        g = build_graph(sub, m=m, ef_construction=ef_construction,
+                        seed=seed + i)
         parts.append(GraphPartition(tag=tag, bitmap=bm, rows=rows,
                                     store=sub, graph=g))
     return PartitionedGraph(partitions=tuple(parts), built_n=n)

@@ -8,7 +8,7 @@ clock, and so is every JAX compile stage that ends while the recorder is
 active, with the innermost span open on its thread:
 
     with obs.record() as rec:
-        build_graph_blocked(store)
+        build_graph(store)
     rec.self_seconds("hnsw.knn"), rec.compile_seconds()
 
 Outside `record()` a span keeps nothing: `span` returns the bare

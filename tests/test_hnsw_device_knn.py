@@ -1,10 +1,12 @@
-"""The HNSW build's candidate kNN on the device against the host recipe it
-replaced: `_knn_among` for exact levels, `_knn_routed` for routed ones,
-then the random long-range extras and the stable sort of both."""
+"""The HNSW build's candidate kNN on the device against the host recipe
+(`hnsw_host_recipe`): `_knn_among` for exact levels, `_knn_routed` for
+routed ones, then the random long-range extras and the stable sort of
+both."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import hnsw_host_recipe as recipe
 from repro.core import VectorStore
 from repro.core import hnsw
 
@@ -19,21 +21,20 @@ def clustered():
     return x.astype(np.float32)
 
 
-def _host_knn(vectors, members, metric, kc, rng, routed, route_expand):
+def _host_knn(vectors, members, metric, kc, rng, routed):
     """The host recipe: `_knn_routed` or `_knn_among`, then the extras."""
     mv = vectors[members]
     n = len(members)
     kc = min(kc, n - 1)
     if routed:
-        ids, dst = hnsw._knn_routed(mv, metric, kc, rng,
-                                    route_expand=route_expand)
+        ids, dst = recipe._knn_routed(mv, metric, kc, rng)
     else:
-        ids, dst = hnsw._knn_among(mv, metric, kc)
+        ids, dst = recipe._knn_among(mv, metric, kc)
     n_rand = min(8, n - 1)
     rnd = rng.randint(0, n, size=(n, n_rand)).astype(np.int64)
     rnd = np.where(rnd == np.arange(n)[:, None], (rnd + 1) % n, rnd)
     ids = np.concatenate([ids, rnd], 1)
-    dst = np.concatenate([dst, hnsw._rows_dist(mv, rnd, metric)], 1)
+    dst = np.concatenate([dst, recipe._rows_dist(mv, rnd, metric)], 1)
     order = np.argsort(dst, axis=1, kind="stable")
     return (np.take_along_axis(ids, order, 1),
             np.take_along_axis(dst, order, 1))
@@ -73,9 +74,9 @@ def test_device_candidates_match_the_host(clustered, metric, routed):
     members = np.arange(N)
     host_rng, dev_rng = np.random.RandomState(5), np.random.RandomState(5)
     want_i, want_d = _host_knn(clustered, members, metric, KC, host_rng,
-                               routed, 3)
+                               routed)
     got_i, got_d, counters = hnsw._knn_device(
-        hnsw._upload_rows(clustered), members, metric, KC, dev_rng, routed, 3)
+        hnsw._upload_rows(clustered), members, metric, KC, dev_rng, routed)
     got_i, got_d = _on_host(got_i, got_d, N, KC)
     assert _rng_state(dev_rng) == _rng_state(host_rng)
     assert got_i.shape == want_i.shape == (N, KC + 8)
@@ -107,9 +108,9 @@ def test_device_candidates_of_a_small_level(clustered):
     rows = np.arange(0, 5 * 13, 5)                   # 13 members of N rows
     vecs = hnsw._upload_rows(clustered)
     want = _host_knn(clustered, rows, "l2", KC, np.random.RandomState(1),
-                     False, 3)
+                     False)
     got_i, got_d, _ = hnsw._knn_device(vecs, rows, "l2", KC,
-                                       np.random.RandomState(1), False, 3)
+                                       np.random.RandomState(1), False)
     got_i, got_d = _on_host(got_i, got_d, 13, KC)
     assert got_i.shape == (13, 12 + 8)
     assert (np.sort(got_i, 1) == np.sort(want[0], 1)).all()
@@ -120,17 +121,17 @@ def test_blocked_build_matches_the_host_recipe(clustered, monkeypatch):
     """The whole routed build: the graph from device candidates against the
     graph from the host recipe, the same rng stream through both."""
     store = VectorStore.build(clustered, metric="l2")
+    monkeypatch.setattr(hnsw, "_EXACT_THRESHOLD", 500)
 
     def build():
-        return np.asarray(hnsw.build_graph_blocked(
-            store, m=12, ef_construction=32, seed=0,
-            exact_threshold=500).neighbors)
+        return np.asarray(hnsw.build_graph(
+            store, m=12, ef_construction=32, seed=0).neighbors)
 
     got = build()
     monkeypatch.setattr(
         hnsw, "_knn_device",
-        lambda vecs, members, metric, kc, rng, routed, expand: _on_device(
-            *_host_knn(clustered, members, metric, kc, rng, routed, expand),
+        lambda vecs, members, metric, kc, rng, routed: _on_device(
+            *_host_knn(clustered, members, metric, kc, rng, routed),
             vecs.shape[0], kc) + ({},))
     want = build()
     assert got.shape == want.shape

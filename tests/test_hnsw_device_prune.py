@@ -1,10 +1,11 @@
 """The HNSW build's neighbour selection on the device against the host
-twins it replaced: `_diversity_prune` for the prune and
+recipe (`hnsw_host_recipe`): `_diversity_prune` for the prune and
 `_augment_reverse_blocked` for the reverse-edge fill of routed levels."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import hnsw_host_recipe as recipe
 from repro.core import VectorStore
 from repro.core import hnsw
 
@@ -25,7 +26,7 @@ def _candidates(vectors, members, metric, routed):
     wide."""
     vecs = hnsw._upload_rows(vectors)
     ids, dst, _ = hnsw._knn_device(vecs, members, metric, KC,
-                                   np.random.RandomState(5), routed, 3)
+                                   np.random.RandomState(5), routed)
     n = len(members)
     w = min(KC, n - 1) + min(8, n - 1)
     return (vecs, ids, dst), (np.asarray(ids)[:n, :w].astype(np.int64),
@@ -38,7 +39,7 @@ def _host_prune(vectors):
         n = len(members)
         w = min(cand.shape[1] - 8, n - 1) + min(8, n - 1)
         out = np.full((vecs.shape[0], m), -1, np.int32)
-        out[:n] = hnsw._diversity_prune(
+        out[:n] = recipe._diversity_prune(
             vectors[members], np.asarray(cand)[:n, :w].astype(np.int64),
             np.asarray(cand_d)[:n, :w], m, metric)
         return jnp.asarray(out), {"kept_share": 0.0, "fill_share": 0.0}
@@ -53,7 +54,7 @@ def _host_reverse(members, pruned):
     fwd = np.where(local >= 0, members[np.maximum(local, 0)], -1)
     level = np.full((members.max() + 1, m), -1, np.int64)
     level[members] = fwd
-    hnsw._augment_reverse_blocked(level, members, fwd, m)
+    recipe._augment_reverse_blocked(level, members, fwd, m)
     edges = int((fwd >= 0).sum())
     return level[members], int((level[members] >= 0).sum()) - edges, edges
 
@@ -67,7 +68,7 @@ def test_device_prune_matches_the_host(clustered, metric):
     got = np.asarray(got)
     assert got.shape == (vecs.shape[0], M) and (got[N:] == -1).all()
     got = got[:N]
-    want = hnsw._diversity_prune(clustered, ids, dst, M, metric)
+    want = recipe._diversity_prune(clustered, ids, dst, M, metric)
     assert (got == want).mean() >= 0.995
     # each row's ids are its own candidates, then -1 padding
     real = got >= 0
@@ -87,7 +88,7 @@ def test_device_prune_of_a_small_level(clustered):
                                                    "l2", False)
     assert (np.asarray(cand)[:13, 12 + 8:] == -1).all()
     got, shares = hnsw._prune_device(vecs, members, cand, cand_d, M, "l2")
-    want = hnsw._diversity_prune(clustered[members], ids, dst, M, "l2")
+    want = recipe._diversity_prune(clustered[members], ids, dst, M, "l2")
     np.testing.assert_array_equal(np.asarray(got)[:13], want)
     assert (np.asarray(got)[13:] == -1).all()
     # 12 others, so at most 12 of the 24 slots fill
@@ -126,11 +127,11 @@ def test_blocked_build_matches_the_host_twins(clustered, monkeypatch):
     against the build with their host twins, the same rng stream through
     both."""
     store = VectorStore.build(clustered, metric="l2")
+    monkeypatch.setattr(hnsw, "_EXACT_THRESHOLD", 500)
 
     def build():
-        return np.asarray(hnsw.build_graph_blocked(
-            store, m=12, ef_construction=32, seed=0,
-            exact_threshold=500).neighbors)
+        return np.asarray(hnsw.build_graph(
+            store, m=12, ef_construction=32, seed=0).neighbors)
 
     got = build()
     monkeypatch.setattr(hnsw, "_prune_device", _host_prune(clustered))

@@ -13,7 +13,8 @@ import pytest
 
 from repro import obs
 from repro.core import SearchParams, VectorStore, make_executor
-from repro.core.hnsw import build_graph_blocked
+from repro.core import hnsw
+from repro.core.hnsw import build_graph
 
 
 @pytest.fixture
@@ -120,10 +121,10 @@ def tiny_store():
     return VectorStore.build(x, metric="l2")
 
 
-def test_build_graph_blocked_spans_every_level(tiny_store):
+def test_build_graph_blocked_spans_every_level(tiny_store, monkeypatch):
+    monkeypatch.setattr(hnsw, "_EXACT_THRESHOLD", 400)
     with obs.record() as rec:
-        g = build_graph_blocked(tiny_store, m=8, ef_construction=24, seed=1,
-                                exact_threshold=400)
+        g = build_graph(tiny_store, m=8, ef_construction=24, seed=1)
     build = [i for i, s in enumerate(rec.spans) if s.name == "hnsw.build"]
     assert len(build) == 1
     children = [s for s in rec.spans if s.parent == build[0]]
@@ -164,8 +165,7 @@ def test_build_graph_blocked_spans_every_level(tiny_store):
         np.asarray(tiny_store.vectors),
         rng.standard_normal((37, 16)).astype(np.float32)]), metric="l2")
     with obs.record() as again:
-        build_graph_blocked(more, m=8, ef_construction=24, seed=2,
-                            exact_threshold=400)
+        build_graph(more, m=8, ef_construction=24, seed=2)
     for stage in ("hnsw.knn", "hnsw.prune", "hnsw.link"):
         at = {i for i, s in enumerate(again.spans) if s.name == stage}
         assert len(at) >= 2
@@ -174,7 +174,7 @@ def test_build_graph_blocked_spans_every_level(tiny_store):
 
 
 def test_graph_search_records_plan_execute_and_anytime(tiny_store):
-    g = build_graph_blocked(tiny_store, m=8, ef_construction=24, seed=1)
+    g = build_graph(tiny_store, m=8, ef_construction=24, seed=1)
     ex = make_executor("navix", tiny_store, graph=g)
     q = jnp.asarray(np.asarray(tiny_store.vectors)[:2] + 0.01)
     bm = jnp.full((2, (tiny_store.n + 31) // 32), 0xFFFFFFFF, jnp.uint32)

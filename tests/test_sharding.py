@@ -231,15 +231,15 @@ def test_streamed_dataset_matches_batch_quantizer():
     assert np.array_equal(blk, np.asarray(s1.vectors)[1024:1536])
 
 
-def test_blocked_graph_builder_routed_path():
-    """Force the routed/blocked code path (exact_threshold below n) and
-    check the graph still navigates to high recall."""
-    from repro.core.hnsw import build_graph_blocked
+def test_blocked_graph_builder_routed_path(monkeypatch):
+    """Force the routed code path (the exact threshold below n) and check
+    the graph still navigates to high recall."""
+    from repro.core import hnsw
+    monkeypatch.setattr(hnsw, "_EXACT_THRESHOLD", 500)
     spec = DatasetSpec("t-blocked", 2_500, 24, "l2", clusters=12)
     store, queries = make_dataset(spec, num_queries=6, seed=1)
     queries = jnp.asarray(queries)
-    g = build_graph_blocked(store, m=12, ef_construction=32, seed=0,
-                            exact_threshold=500)
+    g = hnsw.build_graph(store, m=12, ef_construction=32, seed=0)
     nb = np.asarray(g.neighbors)
     assert nb.max() < store.n and nb.min() >= -1
     words = (store.n + 31) // 32
